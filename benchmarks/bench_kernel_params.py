@@ -32,11 +32,12 @@ import tempfile
 import time
 from typing import Dict, List
 
+import jax
 import numpy as np
 
 from repro.core.engine import RelationEngine
 from repro.core.segtables import OFFLOADED_RELATIONS
-from repro.launch import autotune
+from repro.launch import autotune, roofline
 
 from . import common
 
@@ -117,8 +118,16 @@ def run(quick: bool = True) -> List[str]:
 
     # -- 4. autotune: roofline-ranked candidates, measured, persisted ------
     rows_per_seg = int(pre.tables.NT)
-    cands = autotune.candidate_configs(ns, rows_per_seg,
-                                       max_candidates=3 if quick else 8)
+    kind = jax.devices()[0].device_kind
+    if kind in roofline.PEAKS:
+        cands = autotune.candidate_configs(ns, kind, rows_per_seg,
+                                           max_candidates=3 if quick else 8)
+    else:
+        # no published peaks to rank by on this device: measure only the
+        # built-in default, so the table round trip below still runs
+        cands = [autotune.KernelConfig()]
+        rows.append(common.row("kernel_params/autotune/unranked", 0.0,
+                               f"device_kind={kind}"))
     tune_segs = list(range(min(32, ns)))
 
     def make_engine(cfg):
@@ -147,7 +156,8 @@ def run(quick: bool = True) -> List[str]:
     tune_path = os.environ.get(
         "REPRO_TUNE_TABLE",
         os.path.join(tempfile.gettempdir(), "TUNE_kernel_params.json"))
-    autotune.record("xla", ns, best_cfg, path=tune_path, score_s=best_s)
+    autotune.record("xla", ns, best_cfg, kind, path=tune_path,
+                    score_s=best_s)
     e_def = RelationEngine(pre, RELATIONS, backend="xla", lookahead=0,
                            tune="off")
     e_tun = RelationEngine(pre, RELATIONS, backend="xla", lookahead=0,

@@ -39,6 +39,8 @@ def main() -> None:
                          "(shards x workers cells, DESIGN.md §9) instead "
                          "of its worker sweep")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
 

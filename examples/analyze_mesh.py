@@ -31,6 +31,7 @@ from repro.core.explicit import ExplicitTriangulation
 from repro.core.mesh import segment_mesh
 from repro.core.segtables import precondition
 from repro.data.meshgen import load_dataset
+from repro.launch import compile_cache
 
 RELS = ["VV", "VE", "VF", "VT", "FT", "TT"]
 
@@ -46,6 +47,7 @@ def main():
                     help="cancel persistence pairs below threshold T and "
                          "report the simplified MS complex (DESIGN.md §10)")
     args = ap.parse_args()
+    compile_cache.enable()
     name, workers = args.dataset, args.workers
     mesh = load_dataset(name, scalar_fn=fields.gaussians(2, k=5, sigma=5.0))
     sm = segment_mesh(mesh, capacity=64)

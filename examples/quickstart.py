@@ -11,9 +11,11 @@ from repro.core.engine import RelationEngine
 from repro.core.mesh import segment_mesh
 from repro.core.segtables import precondition
 from repro.data.meshgen import structured_grid
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     # 1. A tetrahedral mesh with a scalar field (4 Gaussian bumps).
     mesh = structured_grid(12, 12, 12,
                            scalar_fn=fields.gaussians(0, k=4, sigma=3.0,

@@ -7,13 +7,14 @@ mid-run injected fault (recovers + replays deterministically).
 
 import argparse
 
-from repro.launch import train
+from repro.launch import compile_cache, train
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     args = ap.parse_args()
+    compile_cache.enable()
     # ~100M params: reuse the trainer with a mid-size custom config by
     # training the mamba2-130m published config (129M params) end to end.
     history = train.main([

@@ -42,6 +42,7 @@ import numpy as np
 
 from ..core.adjacency import complete_adjacency
 from ..core.scheduler import run_collect, run_partitioned, segment_batches
+from ..kernels import ops
 from . import consume
 from .discrete_gradient import GradientField
 
@@ -157,14 +158,14 @@ def _cofacet_rows(ds, pre, face_ids, batch_segments: int = 16,
             cb = ds.get_full_dev_many(("FT",), sl, cols={"FT": 2})
             # batch rows are ascending internal gids of the (sorted) owner
             # segments, so each face resolves by one binary search
-            pos = np.searchsorted(cb.gid, face_ids[sel])
-            rows = jnp.take(cb.M["FT"], jnp.asarray(pos.astype(np.int32)),
-                            axis=0)
+            pos = np.zeros(ops.bucket_rows(len(sel)), np.int32)
+            pos[:len(sel)] = np.searchsorted(cb.gid, face_ids[sel])
+            rows = jnp.take(cb.M["FT"], jnp.asarray(pos), axis=0)
             return sel, rows
 
         def finalize(inter):
             sel, rows = inter
-            return sel, np.asarray(rows)
+            return sel, np.asarray(rows)[:len(sel)]
     else:
         finalize = None
 
@@ -184,7 +185,12 @@ def _cofacet_rows(ds, pre, face_ids, batch_segments: int = 16,
                                  shard_of=shard_of):
         w = min(2, rows.shape[1])
         out[sel, :w] = rows[:, :w]
-    return out
+    # a block row lists cofacets in the producer's LOCAL tet order, which
+    # differs from global order where a face's tets straddle a segment
+    # wall; order them by global id (boundary faces' -1 last), as the
+    # global FT table does, so separatrix ends match on every structure
+    key = np.where(out >= 0, out, np.iinfo(np.int64).max)
+    return np.take_along_axis(out, np.argsort(key, axis=1), axis=1)
 
 
 # contract: device-resident
@@ -198,12 +204,25 @@ def _across_successors(M: jnp.ndarray,   # (p, deg) completed TT, -1 pad
     paired face is the one containing all three of the face's vertices (a
     tet contains a face's vertex triple iff that face is on its boundary) —
     the same predicate the host arm resolves through ``boundary_TF`` face
-    ids, with the same first-match tie-break."""
-    fv = F[jnp.maximum(f, 0)]                                    # (p, 3)
-    nbT = jnp.where(M[..., None] >= 0, T[jnp.maximum(M, 0)], -1)  # (p,deg,4)
-    across = (fv[:, None, :, None] == nbT[:, :, None, :]).any(-1).all(-1)
-    has = across.any(-1)
-    nxt = M[jnp.arange(M.shape[0]), jnp.argmax(across, -1)]
+    ids, with the same first-match tie-break.
+
+    Works on ``(deg, p)`` slabs, one face vertex and one neighbour vertex
+    column at a time: the long axis stays minor, so no ``(p, deg, 4)``
+    intermediate pads its short minor dims out to full TPU lanes (16x the
+    HBM at a million tets)."""
+    Mt = M.T                                               # (deg, p)
+    Mc = jnp.maximum(Mt, 0)
+    fc = jnp.maximum(f, 0)
+    across = Mt >= 0
+    for k in range(F.shape[1]):
+        fk = F[:, k][fc][None, :]                          # (1, p)
+        hit = jnp.zeros_like(across)
+        for c in range(T.shape[1]):
+            hit = hit | (T[:, c][Mc] == fk)
+        across = across & hit
+    has = across.any(0)
+    first = jnp.argmax(across, 0)
+    nxt = jnp.take_along_axis(Mt, first[None, :], axis=0)[0]
     return nxt, has
 
 

@@ -7,7 +7,7 @@ contracts" into errors (docs/DESIGN.md §11 maps each id to its clause):
 =====================  ====================================================
 checker id             contract
 =====================  ====================================================
-``shim-discipline``    JAX 0.4.x pin: raw ``jax.sharding.Mesh``/
+``shim-discipline``    JAX mesh APIs: raw ``jax.sharding.Mesh``/
                        ``AxisType``/``use_mesh``, ``jax.set_mesh``,
                        ``shard_map`` and ``Mesh(...)`` construction are
                        only legal in ``launch/mesh.py``.

@@ -1,13 +1,13 @@
-"""Checker 1 — shim discipline (ROADMAP "JAX pin").
+"""Checker 1 — shim discipline (ROADMAP "JAX mesh APIs").
 
-The container ships JAX 0.4.x: ``jax.sharding.AxisType``, ``jax.set_mesh``,
-``jax.sharding.use_mesh`` and friends do not exist there, and raw ``Mesh``
-construction / ``shard_map`` calls bypass the version shims. ALL mesh
-construction, ambient-mesh installs and shard_map calls must go through
+JAX's mesh APIs (``jax.make_mesh``, ``jax.set_mesh``, ``jax.shard_map``,
+``jax.sharding.Mesh``/``AxisType``/``use_mesh``) have changed name and
+signature from release to release. ALL mesh construction, ambient-mesh
+installs and shard_map calls therefore go through
 ``src/repro/launch/mesh.py`` (``make_mesh``, ``use_mesh``,
 ``shard_map_compat``, and its ``Mesh`` re-export for type annotations) —
-in src, tests and benchmarks alike. This checker turns that prose pin into
-an error on any other module.
+in src, tests and benchmarks alike — so the next API change is one edit in
+that file. This checker turns that rule into an error on any other module.
 """
 
 from __future__ import annotations
@@ -57,7 +57,7 @@ class ShimDiscipline(Checker):
                     if bad:
                         out.append(self.violation(
                             ctx, node,
-                            f"raw JAX 0.4.x-incompatible import "
+                            f"raw JAX mesh-API import "
                             f"'from {mod} import {alias.name}'", HINT))
                         banned_bindings[alias.asname or alias.name] = (
                             f"{mod}.{alias.name}")

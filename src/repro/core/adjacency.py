@@ -389,7 +389,9 @@ def execute_completion_sharded(eng: RelationEngine, plan: CompletionPlan,
                 np.zeros(n, dtype=np.int32))
 
     from ..kernels import completion_gather as _cg
-    cand, clen = all_sum_shards(parts, part_devs)
+    cand, clen, exchange = all_sum_shards(parts, part_devs)
+    if exchange != "none":
+        eng.stat_bump(**{f"exchange_{exchange}": 1})
     if splan.multi_device:
         # commit every chunk's summed matrix to shard 0's device: the psum
         # output is replicated over THIS chunk's participant mesh, which

@@ -283,16 +283,20 @@ class BlockStore:
             occ["bytes"] for occ in self.shard_occupancy())
 
     def shard_occupancy(self) -> List[Dict[str, int]]:
-        """Per-shard device-pool occupancy: backing arrays, entries, bytes.
+        """Per-shard device-pool occupancy: backing arrays, entries, bytes,
+        and the ids of the devices holding them.
 
         This is what keeps ``dev_pool_segments=`` honest per device — the
         bound applies to each shard's pool separately (DESIGN.md §9)."""
         out = []
         for p in self.pools:
             nbytes = 0
+            dev_ids = set()
             for (M, L, _) in p._arrays.values():
                 nbytes += int(M.size) * M.dtype.itemsize
                 nbytes += int(L.size) * L.dtype.itemsize
+                if hasattr(M, "devices"):     # host-arm blocks are numpy
+                    dev_ids.update(d.id for d in M.devices())
             out.append({"arrays": len(p._arrays), "entries": len(p),
-                        "bytes": nbytes})
+                        "bytes": nbytes, "device_ids": sorted(dev_ids)})
         return out

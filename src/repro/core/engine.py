@@ -81,6 +81,7 @@ import numpy as np
 from ..distributed.sharding import ShardPlan
 from ..errors import (
     DeviceLostError,
+    KernelCompileError,
     PoolUploadError,
     RelationError,
     RelationPoisonedError,
@@ -149,6 +150,11 @@ class EngineStats:
     completion_fanout_blocks: int = 0  # block consultations (see docstring)
     completion_raw_neighbors: int = 0  # gathered entries before dedup/self
     completion_neighbors: int = 0      # entries in the final completed rows
+    # Sharded completion exchange (distributed.sharding.all_sum_shards):
+    # chunks summed by a psum over the shards' own devices vs stacked and
+    # summed on one device (shards sharing a device).
+    exchange_psum: int = 0
+    exchange_stack: int = 0
     # Waiting-time breakdown (seconds), paper Fig. 10 phases.
     t_enqueue: float = 0.0
     t_queue: float = 0.0
@@ -329,6 +335,16 @@ class ConsumerBatch:
         return self.M[relation].shape[1]
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _place_rows(pool_M, pool_L, M, L, idx, pos):
+    # contract: device-resident
+    """``pool[pos] = launch[idx]`` for one retained launch's blocks, in
+    place (the output pool is donated). Padded entries repeat a real
+    ``(idx, pos)`` pair, so they write the same values twice."""
+    return (pool_M.at[pos].set(jnp.take(M, idx, axis=0)),
+            pool_L.at[pos].set(jnp.take(L, idx, axis=0)))
+
+
 @functools.partial(jax.jit, static_argnames=("w",))
 def _gather_internal(pool_M, pool_L, flat, gid, w: int):
     # contract: device-resident
@@ -367,10 +383,7 @@ class _Launch:
     def is_ready(self) -> bool:
         if self.hang_until and time.monotonic() < self.hang_until:
             return False              # injected hang: results stay un-ready
-        try:
-            return self.M.is_ready() and self.L.is_ready()
-        except AttributeError:  # pragma: no cover - very old jax
-            return False
+        return self.M.is_ready() and self.L.is_ready()
 
 
 class RelationEngine(StatsHost):
@@ -428,10 +441,12 @@ class RelationEngine(StatsHost):
         # Kernel-parameter resolution (docs/DESIGN.md §4): explicit argument
         # > tuned table entry (tune="auto" or a path) > built-in default.
         # tune="off" skips the table so today's defaults are reproduced
-        # bit-for-bit; a missing/corrupt table silently falls back, so
-        # construction never depends on on-disk tuning state.
+        # bit-for-bit; only entries tuned on this device kind apply.
+        dev0 = (shard_plan.devices[0] if shard_plan is not None
+                and shard_plan.devices[0] is not None else jax.devices()[0])
         tuned = self._load_tuned_config(tune, backend,
-                                        pre.smesh.n_segments)
+                                        pre.smesh.n_segments,
+                                        dev0.device_kind)
         self.batch_max = int(batch_max if batch_max is not None
                              else tuned.get("batch_max", 64))
         self.block_x = int(block_x if block_x is not None
@@ -469,6 +484,8 @@ class RelationEngine(StatsHost):
         # free (so tier-1 single-device runs are byte-identical to shards=1)
         self._multi_dev = shard_plan.multi_device
         self._seg_shard = shard_plan.shard_of_array(np.arange(ns))
+        # kernel shapes already compiled on every shard device at once
+        self._compiled_on_all: set = set()
 
         # Multi-queue: one pending-request queue per offloaded relation
         # (paper §4.5 'Justification of design choices').
@@ -561,23 +578,21 @@ class RelationEngine(StatsHost):
         return tabs
 
     @staticmethod
-    def _load_tuned_config(tune: str, backend: str, n_segments: int) -> Dict:
+    def _load_tuned_config(tune: str, backend: str, n_segments: int,
+                           device_kind: str) -> Dict:
         """Resolve the autotuned kernel-parameter dict for this engine.
 
         ``tune="off"`` returns ``{}`` (built-in defaults); ``"auto"`` looks
         up the default on-disk table (``launch/autotune.py``); any other
-        string is a path to an explicit table. Lookup failures of any kind —
-        missing file, stale version, corrupt JSON — resolve to ``{}`` so
-        construction never fails because of tuning state."""
+        string is a path to an explicit table. Only an entry recorded for
+        this ``device_kind`` applies; a missing table is ``{}``, and an
+        unreadable one raises ``autotune.TuneTableError``."""
         if tune == "off":
             return {}
-        try:
-            from ..launch import autotune
-            cfg = autotune.lookup(backend, n_segments,
-                                  path=None if tune == "auto" else tune)
-            return cfg.to_dict() if cfg is not None else {}
-        except Exception:
-            return {}
+        from ..launch import autotune
+        cfg = autotune.lookup(backend, n_segments, device_kind,
+                              path=None if tune == "auto" else tune)
+        return cfg.to_dict() if cfg is not None else {}
 
     # -- consumer-side API --------------------------------------------------
 
@@ -719,9 +734,10 @@ class RelationEngine(StatsHost):
 
         Blocking behavior, de-dup guarantee and counting are one
         :meth:`get_full_dev` per segment, but blocks sharing a retained
-        launch are assembled with ONE device gather per launch (plus one
-        permutation take) instead of one slice per segment — the completion
-        gather path's pool builder."""
+        launch are placed with ONE jitted gather-and-place per launch into
+        a donated output pool instead of one slice per segment, and a whole
+        launch asked for in its own order is returned as is — the
+        completion gather path's pool builder."""
         with self._consumer_entry("get_full_dev_batch"):
             segments = [int(s) for s in segments]
             ents = [self._dev_entry(relation, s) for s in segments]
@@ -730,12 +746,23 @@ class RelationEngine(StatsHost):
     def _stack_entries(self, ents, pad_to: Optional[int]
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Stack resolved device-pool entries into ``(S, R, deg)`` /
-        ``(S, R)`` arrays (one device gather per retained launch plus one
-        permutation take) — shared by :meth:`get_full_dev_batch` and the
-        mixed-launch arm of :meth:`get_full_dev_many`."""
+        ``(S, R)`` arrays — shared by :meth:`get_full_dev_batch` and the
+        mixed-launch arm of :meth:`get_full_dev_many`.
+
+        One jitted gather-and-place (:func:`_place_rows`) per retained
+        launch, with its row lists padded to a power-of-two bucket, so the
+        compiled shapes depend only on (launch shape, bucket, ``pad_to``)
+        and not on how a batch happens to split across launches. A single
+        launch asked for whole, in its own order and unpadded, is returned
+        without a copy."""
         S = len(ents)
         pad_to = S if pad_to is None else max(pad_to, S)
-        # group segments by source device array (same retained launch)
+        M0, L0, i0 = ents[0]
+        if (pad_to == S and i0 is not None and M0.shape[0] == S
+                and all(M is M0 and i == k
+                        for k, (M, _, i) in enumerate(ents))):
+            return M0, L0
+        # group output slots by source device array (same retained launch)
         groups: Dict[int, Tuple[jnp.ndarray, jnp.ndarray, list, list]] = {}
         for out_pos, (M, L, i) in enumerate(ents):
             if i is None:      # uploaded full block: make it a 1-batch group
@@ -743,34 +770,29 @@ class RelationEngine(StatsHost):
             g = groups.setdefault(id(M), (M, L, [], []))
             g[2].append(i)
             g[3].append(out_pos)
-        parts_M, parts_L = [], []
-        perm = np.empty(pad_to, dtype=np.int32)
-        at = 0
-        for M, L, idx, outs in groups.values():
-            take = jnp.asarray(np.asarray(idx, dtype=np.int32))
-            parts_M.append(jnp.take(M, take, axis=0))
-            parts_L.append(jnp.take(L, take, axis=0))
-            perm[np.asarray(outs)] = at + np.arange(len(idx))
-            at += len(idx)
-        perm[S:] = perm[0]     # padding repeats the first block
-        if len(groups) > 1 and self._multi_dev:
+        first = next(iter(groups.values()))
+        first[2].extend([first[2][0]] * (pad_to - S))  # padding repeats
+        first[3].extend(range(S, pad_to))              # the first block
+        parts = list(groups.values())
+        dev = None
+        if self._multi_dev:
             # a batch spanning shard boundaries mixes devices: normalize all
-            # parts onto one (lowest-id) device before concatenating — pure
-            # data movement, values unchanged
-            devs = {}
-            for p in parts_M:
-                d = next(iter(p.devices()))
-                devs[d.id] = d
+            # parts onto one (lowest-id) device — pure data movement
+            devs = {d.id: d for M, _, _, _ in parts for d in M.devices()}
+            dev = devs[min(devs)]
             if len(devs) > 1:
-                tgt = devs[min(devs)]
-                parts_M = [jax.device_put(p, tgt) for p in parts_M]
-                parts_L = [jax.device_put(p, tgt) for p in parts_L]
-        pool_M = parts_M[0] if len(parts_M) == 1 else jnp.concatenate(parts_M)
-        pool_L = parts_L[0] if len(parts_L) == 1 else jnp.concatenate(parts_L)
-        if len(groups) > 1 or pad_to != S or np.any(perm[:S] != np.arange(S)):
-            ix = jnp.asarray(perm)
-            pool_M = jnp.take(pool_M, ix, axis=0)
-            pool_L = jnp.take(pool_L, ix, axis=0)
+                parts = [(jax.device_put(M, dev), jax.device_put(L, dev),
+                          idx, pos) for M, L, idx, pos in parts]
+        M0, L0 = parts[0][0], parts[0][1]
+        pool_M = jnp.zeros((pad_to,) + M0.shape[1:], M0.dtype, device=dev)
+        pool_L = jnp.zeros((pad_to,) + L0.shape[1:], L0.dtype, device=dev)
+        for M, L, idx, pos in parts:
+            b = ops.bucket_rows(len(idx))
+            idx = np.asarray(idx + [idx[0]] * (b - len(idx)), np.int32)
+            pos = np.asarray(pos + [pos[0]] * (b - len(pos)), np.int32)
+            pool_M, pool_L = _place_rows(pool_M, pool_L, M, L,
+                                         jnp.asarray(idx, device=dev),
+                                         jnp.asarray(pos, device=dev))
         return pool_M, pool_L
 
     def _dev_entry(self, relation: str, segment: int):
@@ -945,7 +967,8 @@ class RelationEngine(StatsHost):
                     at += n
                 flat_dev = jnp.asarray(flat + off)
             else:        # mixed launches / uploads: generic stacked gather
-                pool_M, pool_L = self._stack_entries(ents, len(ents))
+                pool_M, pool_L = self._stack_entries(
+                    ents, ops.bucket_rows(len(ents)))
                 R = pool_M.shape[1]
                 off = np.zeros(rows_pad, dtype=np.int32)
                 at = 0
@@ -1512,8 +1535,10 @@ class RelationEngine(StatsHost):
            or poison the relation and raise (``degrade=False``).
 
         Only :class:`RelationError` subclasses enter the ladder —
-        :class:`RelationWidthError` (a data error, identical on every arm)
-        and non-taxonomy exceptions propagate unchanged."""
+        :class:`RelationWidthError` (a data error, identical on every arm),
+        :class:`KernelCompileError` (the compiler refused the requested
+        backend's kernel) and non-taxonomy exceptions propagate
+        unchanged."""
         policy = self._fault_policy
         attempt = 1
         while True:
@@ -1527,8 +1552,8 @@ class RelationEngine(StatsHost):
             try:
                 launch = self._launch_device(relation, batch, shard,
                                              attempt)
-            except RelationWidthError:
-                raise                 # data error: identical on every arm
+            except (RelationWidthError, KernelCompileError):
+                raise                 # identical on every attempt and arm
             except RelationError as exc:
                 if isinstance(exc, DeviceLostError) \
                         and attempt < policy.max_attempts \
@@ -1599,11 +1624,20 @@ class RelationEngine(StatsHost):
             colg = jnp.take(tabs[_GLOBAL_NAME[ky]], segs, axis=0)
         self._bump(t_prepare=time.perf_counter() - t0)
 
+        # a kernel shape new to a multi-device engine compiles on every
+        # shard device at once, not once per device as each shard meets it
+        compile_on = ()
+        key = (relation, tabX.shape, tabY.shape)
+        if self._multi_dev and key not in self._compiled_on_all:
+            self._compiled_on_all.add(key)
+            compile_on = tuple({d.id: d for d in
+                                self.shard_plan.devices}.values())
         t1 = time.perf_counter()
         M, L = ops.relation_block(
             relation, tabX, tabY, colg, nvl, deg=deg, backend=self.backend,
             block_x=self.block_x, block_y=self.block_y,
-            vv_block=self.vv_block, assembly=self.assembly)
+            vv_block=self.vv_block, assembly=self.assembly,
+            compile_on=compile_on)
         dt = time.perf_counter() - t1
         self._bump(t_kernel=dt, kernel_launches=1,
                    segments_produced=len(batch))

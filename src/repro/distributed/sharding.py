@@ -18,13 +18,16 @@ Roles:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
 
 from ..launch.mesh import Mesh, make_mesh, shard_map_compat
 
@@ -110,8 +113,44 @@ class ShardPlan:
 
 def make_data_mesh(n_shards: int):
     """The ``("data",)`` mesh for the sharded relation engine — built via
-    the launch/mesh.py shims only (JAX 0.4.x pin)."""
+    the launch/mesh.py shims only."""
     return make_mesh((int(n_shards),), ("data",))
+
+
+def compile_on_devices(fn, args: tuple, devices: Sequence[Any]) -> None:
+    """Compile the jitted ``fn`` for ``fn(*args)`` on each of ``devices``,
+    all at once on threads.
+
+    JAX keys an executable by its device, so a kernel that K shards run on
+    K devices compiles K times, and under the engine lock those compiles
+    run one after another. Run together they take about as long as one.
+    The later ``fn(*args)`` on any of these devices reuses its executable
+    (same trace, same lowering, same device). Array leaves of ``args`` give
+    only their shape and dtype; every other argument is passed as is."""
+    def one(device):
+        sh = SingleDeviceSharding(device)
+        lowered = fn.lower(*[
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+            if isinstance(a, jax.Array) else a for a in args])
+        lowered.compile()
+
+    with ThreadPoolExecutor(max_workers=len(devices)) as pool:
+        list(pool.map(one, devices))
+
+
+def _psum_pair(c, l):
+    return jax.lax.psum(c, "data"), jax.lax.psum(l, "data")
+
+
+@functools.lru_cache(maxsize=None)
+def _psum_exchange(devices: tuple):
+    """The ``("data",)`` mesh over ``devices`` and ONE jitted psum over it,
+    built once per device tuple, so repeated exchanges of one shape reuse
+    one executable instead of tracing and compiling a new one each call."""
+    mesh = make_mesh((len(devices),), ("data",), devices=list(devices))
+    spec = P("data")
+    return mesh, jax.jit(shard_map_compat(
+        _psum_pair, mesh=mesh, in_specs=(spec, spec), out_specs=(P(), P())))
 
 
 def all_sum_shards(parts: List[Tuple[Any, Any]],
@@ -122,34 +161,34 @@ def all_sum_shards(parts: List[Tuple[Any, Any]],
     the gathered pool rows, every other shard exact zeros, so an elementwise
     integer sum reconstructs the single-pool candidate matrix bit-for-bit
     (DESIGN.md §9).  With one distinct device per part the sum runs as a
-    ``psum`` over the ``("data",)`` mesh via :func:`shard_map_compat`;
-    otherwise (shards sharing a device, e.g. tier-1 on one CPU device) it
-    falls back to stack+sum on one device — identical integers either way.
+    ``psum`` over a ``("data",)`` mesh of exactly those devices, so every
+    part stays on the device that computed it; otherwise (shards
+    sharing a device, e.g. tier-1 on one CPU device) it stacks and sums on
+    the first part's device — identical integers either way.
+
+    Returns ``(cand, cand_len, exchange)`` with ``exchange`` one of
+    ``"none"`` (a single part), ``"psum"`` or ``"stack"``.
     """
     if len(parts) == 1:
-        return parts[0]
+        return parts[0][0], parts[0][1], "none"
     cands = [p[0] for p in parts]
     lens = [p[1] for p in parts]
     n = len(parts)
     dev_ids = ({d.id for d in devices if d is not None}
                if devices is not None else set())
     if devices is not None and len(dev_ids) == n:
-        mesh = make_mesh((n,), ("data",))
-        mesh_devs = list(mesh.devices.flat)
+        mesh, psum = _psum_exchange(tuple(devices))
         spec = P("data")
-        c_parts = [jax.device_put(c[None], mesh_devs[k])
-                   for k, c in enumerate(cands)]
-        l_parts = [jax.device_put(l[None], mesh_devs[k])
-                   for k, l in enumerate(lens)]
+        c_parts = [jax.device_put(c[None], d)
+                   for c, d in zip(cands, devices)]
+        l_parts = [jax.device_put(l[None], d)
+                   for l, d in zip(lens, devices)]
         gc = jax.make_array_from_single_device_arrays(
             (n,) + cands[0].shape, NamedSharding(mesh, spec), c_parts)
         gl = jax.make_array_from_single_device_arrays(
             (n,) + lens[0].shape, NamedSharding(mesh, spec), l_parts)
-        f = shard_map_compat(
-            lambda c, l: (jax.lax.psum(c, "data"), jax.lax.psum(l, "data")),
-            mesh=mesh, in_specs=(spec, spec), out_specs=(P(), P()))
-        sc, sl = f(gc, gl)
-        return sc[0], sl[0]
+        sc, sl = psum(gc, gl)
+        return sc[0], sl[0], "psum"
     tgt = None
     if devices is not None:
         for d in devices:
@@ -160,7 +199,7 @@ def all_sum_shards(parts: List[Tuple[Any, Any]],
         cands = [jax.device_put(c, tgt) for c in cands]
         lens = [jax.device_put(l, tgt) for l in lens]
     return (jnp.sum(jnp.stack(cands), axis=0),
-            jnp.sum(jnp.stack(lens), axis=0))
+            jnp.sum(jnp.stack(lens), axis=0), "stack")
 
 
 @dataclasses.dataclass

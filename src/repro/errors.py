@@ -26,8 +26,13 @@ messages. The taxonomy mirrors the fault points of the producer pipeline:
 ``RelationPoisonedError``
     A relation exhausted every recovery arm (``degrade=False`` policy) —
     all later consumer calls for it fail fast instead of hanging.
+``KernelCompileError``
+    The compiler refused a kernel for this device (``backend="pallas"``
+    on a relation whose Mosaic lowering is not supported). Non-retryable
+    and never degraded: the engine re-raises it unchanged, so a Pallas
+    run can never quietly fall back to another arm.
 ``RelationWidthError``
-    The one *non-retryable* data error: a produced row holds more
+    The *non-retryable* data error: a produced row holds more
     entries than the preallocated width ``deg[relation]`` (paper §4.6).
     Still a ``ValueError`` for backward compatibility, and re-exported
     from ``repro.core.engine`` where it historically lived.
@@ -107,3 +112,11 @@ class RelationWidthError(RelationError, ValueError):
     :meth:`RelationEngine._integrate` with the ``deg=`` override to use.
     Non-retryable: the same mesh reproduces it on every arm, so the retry
     and degrade machinery re-raises it unchanged."""
+
+
+class KernelCompileError(RelationError):
+    """The device compiler refused a relation's kernel. Carries the
+    compiler's own error as ``__cause__`` and in the message. Non-retryable
+    and outside the degrade arm: retrying recompiles the same program, and
+    serving the relation from another arm would hide that the requested
+    backend does not run on this device."""

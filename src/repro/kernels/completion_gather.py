@@ -26,7 +26,10 @@ Backends (the engine's existing ``backend`` knob):
                              pair blocks (inverse maps and block pool
                              resident in VMEM), with the union epilogue as a
                              shared jitted computation — the same split as
-                             ``segment_relations.py``.
+                             ``segment_relations.py``. The TPU compiler
+                             refuses this kernel (in-kernel gathers across
+                             vregs, and a whole-pool VMEM block), so
+                             ``"pallas"`` raises ``KernelCompileError``.
 
 All ids are i32 (the inverse maps are staged as split ``(seg, gid, row)``
 columns precisely so no x64 is needed on device). ``BIG`` (i32 max) is the
@@ -44,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from .ops import pallas_refusal
 
 BIG = np.int32(np.iinfo(np.int32).max)
 
@@ -327,8 +332,9 @@ def gather_union(
     pair_gid2 = _pad_pow2_1d(pair_gid, -1).reshape(1, -1)
     pair_slot2 = _pad_pow2_1d(pair_slot, -1).reshape(1, -1)
     P = pair_seg.shape[0]
-    cand, cand_len = _resolve_gather_pallas(
-        pool_M, pool_L, inv_seg2, inv_gid2, inv_row2,
-        pair_seg2, pair_gid2, pair_slot2,
-        K, backend == "pallas_interpret", block_pairs)
+    with pallas_refusal("completion gather", backend):
+        cand, cand_len = _resolve_gather_pallas(
+            pool_M, pool_L, inv_seg2, inv_gid2, inv_row2,
+            pair_seg2, pair_gid2, pair_slot2,
+            K, backend == "pallas_interpret", block_pairs)
     return _union_jit(cand[:P], cand_len[:P], pair_gid, pair_at, deg_out)

@@ -64,7 +64,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "q_blk", "k_blk",
                                              "interpret"))
 def flash_attention_bh(q, k, v, *, causal: bool = True, q_blk: int = 512,
-                       k_blk: int = 512, interpret: bool = True):
+                       k_blk: int = 512, interpret: bool = False):
     """q (BH, S, hd), k/v (BH, T, hd) -> (BH, S, hd)."""
     BH, S, hd = q.shape
     T = k.shape[1]
@@ -88,7 +88,7 @@ def flash_attention_bh(q, k, v, *, causal: bool = True, q_blk: int = 512,
     )(q, k, v)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = True,
+def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = False,
                     q_blk: int = 512, k_blk: int = 512):
     """q (B, S, H, hd), k/v (B, T, KV, hd) with KV | H (GQA)."""
     B, S, H, hd = q.shape

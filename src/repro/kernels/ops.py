@@ -3,17 +3,20 @@ relation predicates, and compaction of dense count blocks into the paper's
 padded ``(M, L)`` relation arrays.
 
 Backends:
-  - ``"pallas"``            : pl.pallas_call on a real TPU — sparse entry
-                              assembly emitting (M, L) directly, with a
-                              one-hot counts fallback for EE/FF and
-                              oversize keys (docs/DESIGN.md §4)
-  - ``"pallas_interpret"``  : same kernels executed in interpreter mode
-                              (CPU correctness validation)
   - ``"xla"``               : one fused jit per launch, specialized per
-                              relation with the same sparse entry assembly
-                              — bit-identical to the counts oracle and the
-                              Pallas kernels; the fast path on CPU, used by
-                              the benchmarks in this container
+                              relation with sparse entry assembly — the
+                              default, and the arm that compiles for the
+                              TPU for every relation
+  - ``"pallas"``            : pl.pallas_call compiled for the TPU — sparse
+                              entry assembly emitting (M, L) directly, with
+                              a one-hot counts fallback for EE/FF and
+                              oversize keys (docs/DESIGN.md §4). A kernel
+                              the compiler refuses raises
+                              :class:`~repro.errors.KernelCompileError`
+                              naming the relation; nothing falls back
+  - ``"pallas_interpret"``  : the same kernels in Pallas's interpreter (CPU
+                              correctness validation) — the only arm that
+                              interprets
 
 Both backend families fork sparse/dense under the shared guards in
 :func:`sparse_arm_ok`; ``assembly="dense"`` forces the legacy dense
@@ -22,13 +25,17 @@ epilogue for the benchmark A/B.
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Optional, Tuple
+import re
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..distributed.sharding import compile_on_devices
+from ..errors import KernelCompileError
 from . import ref
 from .segment_relations import (
     relation_counts_meet_pallas,
@@ -62,6 +69,44 @@ PREDICATE = {
     "EF": (2, True), "ET": (2, True), "FT": (3, True),
     "VV": (1, False), "EE": (1, True), "FF": (2, True), "TT": (3, True),
 }
+
+
+# What the Pallas TPU path raises for a kernel it cannot lower or compile,
+# told apart from a bug in the kernel wrappers by its type and wording: a
+# Mosaic compile failure (``MosaicError`` and its ``VerificationError``, or
+# the runtime error Mosaic raised), a lowering rule Mosaic lacks ("Only 2D
+# gather is supported", "Unimplemented primitive ..."), a block shape it
+# rejects, and a CPU backend, which runs Pallas only in interpret mode.
+_REFUSAL_TYPES = (ValueError, NotImplementedError, jax.errors.JaxRuntimeError)
+_REFUSAL_WORDING = re.compile(
+    r"Mosaic|Pallas TPU lowering|Unimplemented|not supported|is supported"
+    r"|interpret mode")
+
+
+def _is_pallas_refusal(e: BaseException) -> bool:
+    """Whether ``e`` is the Pallas TPU compiler refusing a kernel."""
+    if any(t.__name__ == "MosaicError" for t in type(e).__mro__):
+        return True
+    return (isinstance(e, _REFUSAL_TYPES)
+            and _REFUSAL_WORDING.search(str(e)) is not None)
+
+
+@contextlib.contextmanager
+def pallas_refusal(what: str, backend: str = "pallas"):
+    """Under ``backend="pallas"``, re-raise a Pallas lowering/compile
+    refusal inside the block as :class:`KernelCompileError` naming ``what``
+    (a relation, or ``"completion gather"``), with the compiler's error
+    chained. Any other error, and every error under another backend (the
+    interpreter included), propagates unchanged."""
+    try:
+        yield
+    except Exception as e:
+        if backend != "pallas" or not _is_pallas_refusal(e):
+            raise
+        raise KernelCompileError(
+            f"backend='pallas' cannot compile the {what} kernel: "
+            f"{type(e).__name__}: {e}",
+            relation=what if what in PREDICATE else None) from e
 
 
 def counts_meet(tabX: jnp.ndarray, tabY: jnp.ndarray, nvl: int,
@@ -402,6 +447,7 @@ def relation_block(
     block_y: int = 256,
     vv_block: Optional[int] = None,
     assembly: str = "sparse",
+    compile_on: Sequence = (),
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full pipeline: entries (or counts -> predicate) -> (M, L).
 
@@ -414,24 +460,32 @@ def relation_block(
     for EE/FF and oversize keys. ``assembly="dense"`` forces the old dense
     epilogue everywhere (the benchmark A/B arm); ``vv_block`` overrides the
     VV counts-kernel block (defaults to ``block_x``) — both are autotune
-    surface (launch/autotune.py)."""
+    surface (launch/autotune.py). ``compile_on`` lists devices the xla arm
+    is first compiled for, all at once, at these shapes (the sharded
+    engine's shard devices, see
+    :func:`~repro.distributed.sharding.compile_on_devices`)."""
     k, exact = PREDICATE[relation]
     deg = DEFAULT_DEG[relation] if deg is None else deg
     if backend == "xla":
-        return _relation_block_fused(relation, tabX, tabY, col_global,
-                                     nvl, deg, assembly)
-    if assembly == "sparse" and sparse_arm_ok(relation, tabX, tabY, nvl):
-        return relation_entries_pallas(
-            relation, tabX, tabY, col_global, nvl=nvl, deg=deg,
-            interpret=backend == "pallas_interpret")
-    if relation == "VV":
-        C = counts_vv(tabX, nvl, backend=backend,
-                      block=vv_block if vv_block else block_x)
-        mask = predicate(C, k, exact, exclude_diag=True)
-    else:
-        C = counts_meet(tabX, tabY, nvl, backend=backend,
-                        block_x=block_x, block_y=block_y)
-        mask = predicate(C, k, exact, exclude_diag=False)
+        args = (relation, tabX, tabY, col_global, nvl, deg, assembly)
+        if compile_on:
+            compile_on_devices(_relation_block_fused, args, compile_on)
+        return _relation_block_fused(*args)
+    if backend not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown backend {backend!r}")
+    with pallas_refusal(relation, backend):
+        if assembly == "sparse" and sparse_arm_ok(relation, tabX, tabY, nvl):
+            return relation_entries_pallas(
+                relation, tabX, tabY, col_global, nvl=nvl, deg=deg,
+                interpret=backend == "pallas_interpret")
+        if relation == "VV":
+            C = counts_vv(tabX, nvl, backend=backend,
+                          block=vv_block if vv_block else block_x)
+            mask = predicate(C, k, exact, exclude_diag=True)
+        else:
+            C = counts_meet(tabX, tabY, nvl, backend=backend,
+                            block_x=block_x, block_y=block_y)
+            mask = predicate(C, k, exact, exclude_diag=False)
     return compact(mask, col_global.astype(jnp.int32), deg)
 
 

@@ -34,8 +34,16 @@ need NOT be multiples of 128: the counts wrappers pad the simplex axes up to
 a 128 multiple with ``-1`` rows and slice the result (the tail block is
 explicit padding, never an over-covering grid step), and the entry kernels
 pad their entry lanes to a power of two with explicit ``_BIG`` sentinel
-masks. Block sizes are the TPU analogue of the paper's ``t_s``/``t_b``/
-``n_b`` kernel parameters; ``launch/autotune.py`` derives candidates from
+masks.
+
+On a TPU v5e the counts kernels compile; the entry-assembly kernels do not
+(their binary search and ``M`` fill gather across the whole lane vector,
+and Mosaic gathers only within one (8, 128) vreg), so
+``ops.relation_block`` raises ``KernelCompileError`` for them on
+``backend="pallas"``.
+
+Block sizes are the TPU analogue of the paper's ``t_s``/``t_b``/``n_b``
+kernel parameters; ``launch/autotune.py`` derives candidates from
 the roofline model and ``benchmarks/bench_kernel_params.py`` measures them.
 """
 
@@ -48,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BIG = np.int32(np.iinfo(np.int32).max)
 
@@ -128,7 +137,7 @@ def relation_counts_meet_pallas(
     tabX_t: jnp.ndarray,   # (B, ax, NX) int32, transposed table, -1 padded
     tabY_t: jnp.ndarray,   # (B, ay, NY)
     *, nvl: int, block_x: int = 256, block_y: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """C (B, NX, NY) int32 shared-vertex counts."""
     B, ax, NX = tabX_t.shape
@@ -164,7 +173,7 @@ def relation_counts_meet_pallas(
     jax.jit, static_argnames=("nvl", "block", "interpret"))
 def relation_counts_vv_pallas(
     T_local_t: jnp.ndarray,  # (B, 4, NT) int32 transposed tet table
-    *, nvl: int, block: int = 128, interpret: bool = True,
+    *, nvl: int, block: int = 128, interpret: bool = False,
 ) -> jnp.ndarray:
     """C (B, nvl, nvl) int32 shared-tet counts between local vertices."""
     B, four, NT = T_local_t.shape
@@ -193,7 +202,7 @@ def relation_counts_vv_pallas(
 # vectors with E a power of two; invalid lanes carry the _BIG sentinel.
 # The TPU has no sort/scan/scatter primitives inside Pallas, so:
 #   - sorting is a bitonic compare-exchange network whose partner exchange
-#    (lane XOR j) is a reshape+flip, not a gather;
+#     (lane XOR j) is two lane rotations and a select, not a gather;
 #   - the segmented scan of ops._invert_entries becomes a per-row binary
 #     search over the sorted keys (same idiom as completion_gather.py);
 #   - scatter-free placement: row r's entries sit at sorted positions
@@ -213,13 +222,21 @@ def _pad_lanes(x: jnp.ndarray, E: int, fill) -> jnp.ndarray:
         [x, jnp.full((1, E - n), fill, x.dtype)], axis=1)
 
 
+def _roll_lanes(x: jnp.ndarray, shift: int) -> jnp.ndarray:
+    """``jnp.roll(x, shift, axis=1)`` on (1, E) lanes as a TPU lane
+    rotation: ``out[i] = x[(i - shift) mod E]``."""
+    return pltpu.roll(x, shift % x.shape[1], 1)
+
+
 def _bitonic_sort_lanes(key, payloads):
     """Bitonic sort of (1, E) lanes by ``key`` ascending (E a power of two);
-    ``payloads`` ride along. Partner exchange for lane XOR j is the
-    reshape/flip trick, so no gathers. Ties keep both lanes in place; every
-    key family sorted here is either tie-free or tie-insensitive (equal keys
-    always carry equal payloads, or only sentinel lanes tie), so an unstable
-    network is bit-identical to ``jax.lax.sort`` downstream."""
+    ``payloads`` ride along. The partner of lane i is lane ``i XOR j``:
+    ``i + j`` where bit j of i is clear, ``i - j`` where it is set, so the
+    exchange is two lane rotations and a select — no gathers. Ties keep
+    both lanes in place; every key family sorted here is either tie-free
+    or tie-insensitive (equal keys always carry equal payloads, or only
+    sentinel lanes tie), so an unstable network is bit-identical to
+    ``jax.lax.sort`` downstream."""
     _, E = key.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, E), 1)
     k = 2
@@ -227,11 +244,12 @@ def _bitonic_sort_lanes(key, payloads):
         up = (lane & k) == 0
         j = k // 2
         while j >= 1:
-            def partner(x, j=j):
-                return jnp.flip(
-                    x.reshape(E // (2 * j), 2, j), axis=1).reshape(1, E)
-            pk = partner(key)
             low = (lane & j) == 0        # this lane is the pair's low index
+
+            def partner(x, j=j, low=low):
+                return jnp.where(low, _roll_lanes(x, E - j),
+                                 _roll_lanes(x, j))
+            pk = partner(key)
             take_min = low == up
             want = jnp.where(take_min, pk < key, pk > key)
             key = jnp.where(want, pk, key)
@@ -248,8 +266,7 @@ def _cummax_lanes(x: jnp.ndarray) -> jnp.ndarray:
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, E), 1)
     d = 1
     while d < E:
-        shifted = jnp.where(lane >= d, jnp.roll(x, d, axis=1),
-                            jnp.int32(-1))
+        shifted = jnp.where(lane >= d, _roll_lanes(x, d), jnp.int32(-1))
         x = jnp.maximum(x, shifted)
         d *= 2
     return x
@@ -290,14 +307,14 @@ def _emit_entries(key, val, M_ref, L_ref, *, R: int, O: int, deg: int):
     _, E = key.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, E), 1)
     key, (val,) = _bitonic_sort_lanes(key, [val])
-    dup = (lane > 0) & (key == jnp.roll(key, 1, axis=1))
+    dup = (lane > 0) & (key == _roll_lanes(key, 1))
     key = jnp.where(dup, _BIG, key)
     key, (val,) = _bitonic_sort_lanes(key, [val])
 
     queries = jax.lax.broadcasted_iota(jnp.int32, (1, R + 1), 1) * O
     starts = _lower_bound_lanes(key, queries)       # (1, R+1)
     L = starts[:, 1:] - starts[:, :-1]              # (1, R) true counts
-    L_ref[0, :] = L[0, :]
+    L_ref[0, :, :] = L
 
     d2 = jax.lax.broadcasted_iota(jnp.int32, (R, deg), 1)
     st = starts[0, :R].reshape(R, 1)
@@ -346,7 +363,7 @@ def _member_entries_kernel(taby_ref, colg_ref, M_ref, L_ref, *,
     relates to simplex y iff v ∈ verts(y) (exact C == 1: a simplex lists
     distinct vertices)."""
     order = jax.lax.broadcasted_iota(jnp.int32, (1, NY), 1)
-    colg = colg_ref[0, :].reshape(1, NY)
+    colg = colg_ref[0, 0, :].reshape(1, NY)
     keys, vals = [], []
     for c in range(ay):
         v = taby_ref[0, c, :].reshape(1, NY)
@@ -362,7 +379,7 @@ def _vv_entries_kernel(tet_ref, colg_ref, M_ref, L_ref, *,
     """VV: the 12 ordered vertex pairs of each tet are the entries (C >= 1
     off-diagonal — a tet's vertices are distinct, so the diagonal never
     appears; repeated pairs from different tets dedup in _emit_entries)."""
-    colg = colg_ref[0, :]
+    colg = colg_ref[0, 0, :]
     rows4 = [tet_ref[0, c, :].reshape(1, NT) for c in range(4)]
     keys, vals = [], []
     for a in range(4):
@@ -397,10 +414,10 @@ def _tt_entries_kernel(tet_ref, colg_ref, M_ref, L_ref, *,
     tjd = _pad_lanes(jnp.concatenate(tids, axis=1), EJ, 0)
     fkey, (tjd,) = _bitonic_sort_lanes(fkey, [tjd])
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, EJ), 1)
-    nk = jnp.roll(fkey, -1, axis=1)
-    nt = jnp.roll(tjd, -1, axis=1)
+    nk = _roll_lanes(fkey, -1)
+    nt = _roll_lanes(tjd, -1)
     eq = (fkey == nk) & (fkey != _BIG) & (lane < EJ - 1)
-    colg = colg_ref[0, :]
+    colg = colg_ref[0, 0, :]
     k1 = jnp.where(eq, tjd * NT + nt, _BIG)
     v1 = _gather_lanes(colg, jnp.maximum(nt, 0))
     k2 = jnp.where(eq, nt * NT + tjd, _BIG)
@@ -448,7 +465,7 @@ def _sub_entries_kernel(tabx_ref, taby_ref, colg_ref, M_ref, L_ref, *,
     ok = (~is_x) & (key != _BIG) & (lastX >= 0) & (xkey == key - 1)
     row = _gather_lanes(payload[0, :], take)
     order = jnp.where(ok, payload, 0)
-    val = _gather_lanes(colg_ref[0, :], order)
+    val = _gather_lanes(colg_ref[0, 0, :], order)
     ekey = jnp.where(ok, row * NY + order, _BIG)
     _emit_entries(ekey, val, M_ref, L_ref, R=NX, O=NY, deg=deg)
 
@@ -460,7 +477,7 @@ def relation_entries_pallas(
     tabX: jnp.ndarray,          # (B, NX, ax) rows table (T_local for VV/TT)
     tabY: jnp.ndarray,          # (B, NY, ay) cols table (ignored for VV/TT)
     col_global: jnp.ndarray,    # (B, NY) local -> global map for columns
-    *, nvl: int, deg: int, interpret: bool = True,
+    *, nvl: int, deg: int, interpret: bool = False,
 ) -> tuple:
     """Sparse Pallas producer: ``(M (B, R, deg), L (B, R))`` emitted
     directly, one batched segment per grid step, bit-identical to the xla
@@ -468,7 +485,9 @@ def relation_entries_pallas(
     Callers (``ops.relation_block``) route EE/FF and oversize-key cases to
     the one-hot counts fallback, mirroring the xla guards."""
     B = tabX.shape[0]
-    colg = col_global.astype(jnp.int32)
+    # (B, N) operands travel as (B, 1, N): a TPU block's last two dims
+    # must be (8, 128)-aligned or span the array, and (1, N) spans it
+    colg = col_global.astype(jnp.int32)[:, None, :]
     if relation in ("VE", "VF", "VT"):
         _, NY, ay = tabY.shape
         E = _next_pow2(ay * NY)
@@ -476,7 +495,7 @@ def relation_entries_pallas(
             _member_entries_kernel, NY=NY, ay=ay, E=E, nvl=nvl, deg=deg)
         ins = [jnp.swapaxes(tabY, 1, 2), colg]
         in_specs = [pl.BlockSpec((1, ay, NY), lambda b: (b, 0, 0)),
-                    pl.BlockSpec((1, NY), lambda b: (b, 0))]
+                    pl.BlockSpec((1, 1, NY), lambda b: (b, 0, 0))]
         R = nvl
     elif relation == "VV":
         _, NT, four = tabX.shape
@@ -485,7 +504,7 @@ def relation_entries_pallas(
             _vv_entries_kernel, NT=NT, E=E, nvl=nvl, deg=deg)
         ins = [jnp.swapaxes(tabX, 1, 2), colg]
         in_specs = [pl.BlockSpec((1, four, NT), lambda b: (b, 0, 0)),
-                    pl.BlockSpec((1, colg.shape[1]), lambda b: (b, 0))]
+                    pl.BlockSpec((1, 1, colg.shape[2]), lambda b: (b, 0, 0))]
         R = nvl
     elif relation == "TT":
         _, NT, four = tabX.shape
@@ -495,7 +514,7 @@ def relation_entries_pallas(
             _tt_entries_kernel, NT=NT, EJ=EJ, E=E, nvl=nvl, deg=deg)
         ins = [jnp.swapaxes(tabX, 1, 2), colg]
         in_specs = [pl.BlockSpec((1, four, NT), lambda b: (b, 0, 0)),
-                    pl.BlockSpec((1, NT), lambda b: (b, 0))]
+                    pl.BlockSpec((1, 1, NT), lambda b: (b, 0, 0))]
         R = NT
     elif relation in ("EF", "ET", "FT"):
         _, NX, ax = tabX.shape
@@ -508,7 +527,7 @@ def relation_entries_pallas(
         ins = [jnp.swapaxes(tabX, 1, 2), jnp.swapaxes(tabY, 1, 2), colg]
         in_specs = [pl.BlockSpec((1, ax, NX), lambda b: (b, 0, 0)),
                     pl.BlockSpec((1, ay, NY), lambda b: (b, 0, 0)),
-                    pl.BlockSpec((1, NY), lambda b: (b, 0))]
+                    pl.BlockSpec((1, 1, NY), lambda b: (b, 0, 0))]
         R = NX
     else:
         raise KeyError(f"no sparse entry kernel for relation {relation!r}")
@@ -517,9 +536,9 @@ def relation_entries_pallas(
         grid=(B,),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, R, deg), lambda b: (b, 0, 0)),
-                   pl.BlockSpec((1, R), lambda b: (b, 0))],
+                   pl.BlockSpec((1, 1, R), lambda b: (b, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, R, deg), jnp.int32),
-                   jax.ShapeDtypeStruct((B, R), jnp.int32)],
+                   jax.ShapeDtypeStruct((B, 1, R), jnp.int32)],
         interpret=interpret,
     )(*ins)
-    return M, L
+    return M, L[:, 0, :]

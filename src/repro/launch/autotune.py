@@ -9,18 +9,21 @@ heuristics, this layer
      scored through :func:`repro.launch.roofline.kernel_roofline`),
   2. lets ``benchmarks/bench_kernel_params.py`` measure them on the real
      engine (:func:`measure_engine`), and
-  3. persists the winner per ``(backend, mesh-size bucket)`` in a small
-     on-disk JSON table that :class:`~repro.core.engine.RelationEngine`
-     consults at construction (``tune="auto" | "off" | <path>``).
+  3. persists the winner per ``(device kind, backend, mesh-size bucket)``
+     in a small on-disk JSON table that
+     :class:`~repro.core.engine.RelationEngine` consults at construction
+     (``tune="auto" | "off" | <path>``).
 
-Config key: the mesh size is bucketed to the next power of two (same
-bucketing as ``ops.bucket_rows``) so one tuned entry covers a range of
-meshes; the backend is part of the key because the Pallas sparse-assembly
-kernels and the fused xla oracle have different sweet spots. Lookup order
-inside the engine: explicit constructor argument > tuned table entry >
-built-in default. Tables carry a ``version`` field — a version mismatch
-invalidates the whole table (treated as missing), so stale entries from an
-older kernel generation can never silently configure a new engine.
+Config key: ``jax``'s ``device_kind`` comes first, so an entry measured on
+one device (say the CPU) is never applied on another (a TPU). The mesh size
+is bucketed to the next power of two (same bucketing as
+``ops.bucket_rows``) so one tuned entry covers a range of meshes; the
+backend is part of the key because the Pallas sparse-assembly kernels and
+the fused xla oracle have different sweet spots. Lookup order inside the
+engine: explicit constructor argument > tuned table entry > built-in
+default. A missing table is no table; a table of another ``version`` is
+ignored whole (its keys cannot apply); a table that cannot be read as one
+raises :class:`TuneTableError` instead of silently configuring nothing.
 """
 
 from __future__ import annotations
@@ -33,7 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from .roofline import kernel_roofline
 
-TABLE_VERSION = 1
+TABLE_VERSION = 2
 _DEFAULT_NAME = "TUNE_kernel_params.json"
 
 # amortized per-launch dispatch overhead (host->device + jit call), the
@@ -71,30 +74,40 @@ def default_path() -> str:
                           os.path.join(os.getcwd(), _DEFAULT_NAME))
 
 
+class TuneTableError(ValueError):
+    """The tuning table exists but is not a readable table."""
+
+
 def bucket(n_segments: int) -> int:
     """Mesh-size bucket: next power of two >= n_segments (min 1)."""
     n = max(1, int(n_segments))
     return 1 << (n - 1).bit_length()
 
 
-def table_key(backend: str, n_segments: int) -> str:
-    return f"{backend}/{bucket(n_segments)}"
+def table_key(device_kind: str, backend: str, n_segments: int) -> str:
+    return f"{device_kind}/{backend}/{bucket(n_segments)}"
 
 
 def load_table(path: Optional[str] = None) -> Dict[str, Dict]:
-    """Load the tuning table; any failure (missing file, bad JSON, version
-    mismatch) returns an empty table — tuning state can never break an
-    engine construction."""
+    """Load the tuning table's configs: ``{}`` when the file is missing or
+    was written for another ``TABLE_VERSION``; :class:`TuneTableError` when
+    it is not JSON or has no ``configs`` object."""
     path = path or default_path()
     try:
         with open(path) as f:
-            data = json.load(f)
-        if not isinstance(data, dict) or data.get("version") != TABLE_VERSION:
-            return {}
-        configs = data.get("configs")
-        return configs if isinstance(configs, dict) else {}
-    except (OSError, ValueError):
+            text = f.read()
+    except FileNotFoundError:
         return {}
+    try:
+        data = json.loads(text)
+    except ValueError as e:
+        raise TuneTableError(f"tuning table {path} is not JSON: {e}") from e
+    if not isinstance(data, dict) or not isinstance(data.get("configs"),
+                                                    dict):
+        raise TuneTableError(f"tuning table {path} has no 'configs' object")
+    if data.get("version") != TABLE_VERSION:
+        return {}
+    return data["configs"]
 
 
 def save_table(configs: Dict[str, Dict], path: Optional[str] = None) -> str:
@@ -106,34 +119,36 @@ def save_table(configs: Dict[str, Dict], path: Optional[str] = None) -> str:
     return path
 
 
-def lookup(backend: str, n_segments: int,
+def lookup(backend: str, n_segments: int, device_kind: str,
            path: Optional[str] = None) -> Optional[KernelConfig]:
-    """The engine-side read: tuned config for (backend, mesh bucket), or
-    ``None`` when nothing is recorded."""
-    entry = load_table(path).get(table_key(backend, n_segments))
-    if not isinstance(entry, dict):
+    """The engine-side read: tuned config for (device kind, backend, mesh
+    bucket), or ``None`` when nothing is recorded for that key."""
+    key = table_key(device_kind, backend, n_segments)
+    entry = load_table(path).get(key)
+    if entry is None:
         return None
     try:
         return KernelConfig.from_dict(entry)
-    except (TypeError, ValueError):
-        return None
+    except (AttributeError, TypeError, ValueError) as e:
+        raise TuneTableError(
+            f"tuning table entry {key!r} is not a kernel config: {e}") from e
 
 
 def record(backend: str, n_segments: int, config: KernelConfig,
-           path: Optional[str] = None,
+           device_kind: str, path: Optional[str] = None,
            score_s: Optional[float] = None) -> str:
-    """Persist a measured winner for (backend, mesh bucket)."""
+    """Persist a measured winner for (device kind, backend, mesh bucket)."""
     configs = load_table(path)
     entry = config.to_dict()
     if score_s is not None:
         entry["score_s"] = float(score_s)
-    configs[table_key(backend, n_segments)] = entry
+    configs[table_key(device_kind, backend, n_segments)] = entry
     return save_table(configs, path)
 
 
 def _predicted_launch_s(cfg: KernelConfig, n_segments: int,
                         rows_per_segment: int, arity: int,
-                        deg: int) -> float:
+                        deg: int, device_kind: str) -> float:
     """Analytic time per SEGMENT for one candidate: roofline memory/compute
     terms for a ``batch_max``-segment launch plus the launch overhead, both
     amortized over the batch. i32 tables in, (M, L) entry blocks out."""
@@ -143,7 +158,7 @@ def _predicted_launch_s(cfg: KernelConfig, n_segments: int,
     out_bytes = rows * (deg + 1) * 4         # M + L
     # sort-join assembly: ~O(rows log rows) compare-exchange flops
     flops = rows * arity * max(1, rows_per_segment.bit_length()) * 4.0
-    terms = kernel_roofline(flops, in_bytes + out_bytes)
+    terms = kernel_roofline(flops, in_bytes + out_bytes, device_kind)
     t_launch = max(terms["t_compute_s"], terms["t_memory_s"])
     # oversized blocks waste grid cover on small tables; fold a mild
     # utilization penalty so candidates differ on block shape too
@@ -151,13 +166,16 @@ def _predicted_launch_s(cfg: KernelConfig, n_segments: int,
     return (t_launch / max(util, 1 / 16) + _LAUNCH_OVERHEAD_S) / b
 
 
-def candidate_configs(n_segments: int, rows_per_segment: int = 512,
+def candidate_configs(n_segments: int, device_kind: str,
+                      rows_per_segment: int = 512,
                       arity: int = 4, deg: int = 32,
                       max_candidates: int = 8) -> List[KernelConfig]:
     """Roofline-ranked candidate configs for a mesh of ``n_segments``
-    segments with ``rows_per_segment`` table rows each. The returned list
-    (best predicted first) is what the benchmark actually measures — the
-    model prunes the sweep, the measurement picks the winner."""
+    segments with ``rows_per_segment`` table rows each, scored with the
+    peaks of ``device_kind`` (``roofline.peaks`` raises for a device with
+    none). The returned list (best predicted first) is what the benchmark
+    actually measures — the model prunes the sweep, the measurement picks
+    the winner."""
     cands = []
     for bx in (128, 256, 512):
         for by in (128, 256, 512):
@@ -168,7 +186,7 @@ def candidate_configs(n_segments: int, rows_per_segment: int = 512,
                         vv_block=None if bx == by else min(bx, by),
                         batch_max=bm, bucket_floor=floor))
     cands.sort(key=lambda c: _predicted_launch_s(
-        c, n_segments, rows_per_segment, arity, deg))
+        c, n_segments, rows_per_segment, arity, deg, device_kind))
     return cands[:max_candidates]
 
 

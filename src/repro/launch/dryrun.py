@@ -122,14 +122,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     if save_hlo:
         with open(save_hlo, "w") as f:
             f.write(hlo)
     costs = roofline.analyze(hlo, n_dev)
-    terms = roofline.roofline_terms(costs)
+    terms = roofline.roofline_terms(costs, roofline.DRYRUN_DEVICE_KIND)
     mflops = roofline.model_flops(cfg, shape)
 
     rec.update({

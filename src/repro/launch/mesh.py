@@ -1,20 +1,17 @@
-"""Production mesh construction + JAX version compatibility shims.
+"""Production mesh construction + the one place that touches JAX's mesh APIs.
 
 Import of this module never touches jax device state; meshes are built by
 functions only (the dry-run sets XLA_FLAGS before any jax import).
 
-The repo is pinned to the container's JAX (0.4.x), where several mesh APIs
-that newer code uses do not exist yet. Everything that builds or installs
-a mesh must go through the shims here instead of calling jax directly:
+Everything that builds or installs a mesh goes through the shims here
+instead of calling jax directly (contractcheck's ``shim-discipline`` rule),
+so a change of JAX's mesh API is one edit in this file:
 
-  - :func:`make_mesh` — ``jax.make_mesh`` with ``axis_types=Auto`` where
-    ``jax.sharding.AxisType`` exists (jax >= 0.5), plain ``jax.make_mesh``
-    otherwise (0.4.x has no axis_types kwarg; Auto is the 0.4.x behaviour).
-  - :func:`use_mesh` — context manager equivalent of ``jax.set_mesh``:
-    prefers ``jax.set_mesh``, then ``jax.sharding.use_mesh``, then the
-    legacy ``with mesh:`` thread-resources context on 0.4.x.
-  - :func:`shard_map_compat` — ``jax.shard_map`` / experimental shard_map
-    with the ``check_vma``/``check_rep`` kwarg rename papered over.
+  - :func:`make_mesh` — ``jax.make_mesh`` with explicitly ``Auto`` axis
+    types, optionally over a given device list.
+  - :func:`use_mesh` — ``jax.set_mesh``: installs the ambient mesh.
+  - :func:`shard_map_compat` — ``jax.shard_map`` with its ``check_vma``
+    flag exposed as ``check``.
   - :data:`Mesh` — re-export of ``jax.sharding.Mesh`` for type annotations.
     contractcheck's shim-discipline rule forbids importing it from
     ``jax.sharding`` anywhere else, so every raw-API touch stays in this
@@ -32,41 +29,24 @@ __all__ = ["Mesh", "make_mesh", "use_mesh", "shard_map_compat",
            "make_production_mesh", "make_test_mesh", "batch_axes"]
 
 
-def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` (explicitly Auto axis types on
-    jax versions that distinguish them)."""
-    if hasattr(jax.sharding, "AxisType"):
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types; ``devices`` fixes which
+    devices the mesh spans instead of letting JAX pick them."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devices)
 
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """Install ``mesh`` as the ambient mesh, whatever this jax calls that."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield
-    elif hasattr(jax.sharding, "use_mesh"):
-        with jax.sharding.use_mesh(mesh):
-            yield
-    else:  # 0.4.x: the legacy thread-resources mesh context
-        with mesh:
-            yield
+    """Install ``mesh`` as the ambient mesh for the ``with`` block."""
+    with jax.set_mesh(mesh):
+        yield
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """shard_map across the check_vma (>= 0.6) / check_rep (< 0.6) rename."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    """``jax.shard_map``; ``check`` is its ``check_vma`` flag."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def _mk(shape, axes):

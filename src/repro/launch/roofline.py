@@ -10,8 +10,9 @@ recurses through fusions/calls, and accumulates
   - HBM traffic estimate (operand + result bytes of top-level ops),
   - collective bytes moved per device, with ring factors per primitive.
 
-Hardware model (TPU v5e targets from the assignment):
-  peak = 197 TFLOP/s bf16 per chip; HBM bw = 819 GB/s; ICI ~ 50 GB/s/link.
+Hardware model: :data:`PEAKS`, one row of published per-chip peaks per
+``jax`` ``device_kind``, each with its source. A device without a row is an
+error (:func:`peaks`), never a default.
 """
 
 from __future__ import annotations
@@ -20,9 +21,44 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    bf16_flops: float        # FLOP/s
+    hbm_bytes_s: float       # HBM bandwidth, bytes/s
+    ici_link_bytes_s: float  # inter-chip interconnect per link, bytes/s
+    source: str
+
+
+PEAKS: Dict[str, ChipPeaks] = {
+    # 1,600 Gbit/s of ICI per chip over its 4 links = 50 GB/s per link
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bytes_s=819e9, ici_link_bytes_s=50e9,
+        source="Google Cloud documentation, 'TPU v5e' "
+               "(cloud.google.com/tpu/docs/v5e): per chip 197 TFLOP/s "
+               "bf16, 393 TOP/s int8, 16 GB HBM2 at 819 GB/s, "
+               "1,600 Gbit/s ICI"),
+}
+
+# the chip the dry-run's production pods are built from
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+class UnknownDeviceError(KeyError):
+    """No published peaks are recorded for this ``device_kind``."""
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The :data:`PEAKS` row for ``device_kind``; raises
+    :class:`UnknownDeviceError` for a device without one."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peaks for device_kind {device_kind!r}; add a "
+            f"row with its source to repro.launch.roofline.PEAKS") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -296,10 +332,11 @@ def analyze(hlo: str, n_devices: int) -> HloCosts:
     return comp_cost(entry) if entry else HloCosts()
 
 
-def roofline_terms(costs: HloCosts) -> Dict[str, float]:
-    tc = costs.flops / PEAK_FLOPS
-    tm = costs.hbm_bytes / HBM_BW
-    tx = costs.ici_bytes / ICI_BW
+def roofline_terms(costs: HloCosts, device_kind: str) -> Dict[str, float]:
+    p = peaks(device_kind)
+    tc = costs.flops / p.bf16_flops
+    tm = costs.hbm_bytes / p.hbm_bytes_s
+    tx = costs.ici_bytes / p.ici_link_bytes_s
     dom = max((tc, "compute"), (tm, "memory"), (tx, "collective"))[1]
     total = max(tc, tm, tx)
     return {
@@ -309,7 +346,7 @@ def roofline_terms(costs: HloCosts) -> Dict[str, float]:
     }
 
 
-def kernel_roofline(flops: float, hbm_bytes: float,
+def kernel_roofline(flops: float, hbm_bytes: float, device_kind: str,
                     ici_bytes: float = 0.0) -> Dict[str, float]:
     """Roofline terms for a single relation kernel launch, from analytic
     (not HLO-parsed) cost estimates. This is the scoring function behind
@@ -318,7 +355,7 @@ def kernel_roofline(flops: float, hbm_bytes: float,
     (block, batch) configuration."""
     return roofline_terms(HloCosts(flops=float(flops),
                                    hbm_bytes=float(hbm_bytes),
-                                   ici_bytes=float(ici_bytes)))
+                                   ici_bytes=float(ici_bytes)), device_kind)
 
 
 def model_flops(cfg, shape) -> float:

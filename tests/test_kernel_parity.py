@@ -4,6 +4,7 @@ oracle for every relation, through the REAL engine dispatch — including
 the EE/FF dense fallback arm and the ``RelationWidthError`` overflow path
 — plus the autotune round trip (``launch/autotune.py``)."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -114,10 +115,12 @@ def test_autotune_roundtrip(pre, tmp_path):
                                 batch_max=8, bucket_floor=2)
     path = str(tmp_path / "tune.json")
     ns = pre.smesh.n_segments
-    autotune.record("xla", ns, cfg, path=path, score_s=1.0)
-    assert autotune.lookup("xla", ns, path=path) == cfg
-    # other backends / other mesh buckets miss
-    assert autotune.lookup("pallas", ns, path=path) is None
+    kind = jax.devices()[0].device_kind
+    autotune.record("xla", ns, cfg, kind, path=path, score_s=1.0)
+    assert autotune.lookup("xla", ns, kind, path=path) == cfg
+    # other backends / other device kinds / other mesh buckets miss
+    assert autotune.lookup("pallas", ns, kind, path=path) is None
+    assert autotune.lookup("xla", ns, "TPU v5 lite", path=path) is None
 
     eng = RelationEngine(pre, ["VV"], backend="xla", lookahead=0, tune=path)
     assert (eng.batch_max, eng.block_x, eng.block_y, eng.vv_block,
@@ -143,25 +146,46 @@ def test_tune_off_matches_built_in_defaults(pre):
 
 
 def test_corrupt_table_falls_back_to_defaults(pre, tmp_path):
+    """A table that cannot be read raises (it never silently configures
+    the defaults); a missing or stale-version table is no table."""
     bad = tmp_path / "bad.json"
     bad.write_text("{not json", encoding="utf-8")
-    eng = RelationEngine(pre, ["VV"], backend="xla", tune=str(bad))
+    with pytest.raises(autotune.TuneTableError):
+        RelationEngine(pre, ["VV"], backend="xla", tune=str(bad))
+    kind = jax.devices()[0].device_kind
+    key = autotune.table_key(kind, "xla", pre.smesh.n_segments)
+    bad.write_text('{"version": %d, "configs": {"%s": [1]}}'
+                   % (autotune.TABLE_VERSION, key), encoding="utf-8")
+    with pytest.raises(autotune.TuneTableError):
+        RelationEngine(pre, ["VV"], backend="xla", tune=str(bad))
+    eng = RelationEngine(pre, ["VV"], backend="xla",
+                         tune=str(tmp_path / "missing.json"))
     assert (eng.batch_max, eng.block_x, eng.block_y) == (64, 256, 256)
     stale = tmp_path / "stale.json"
     stale.write_text('{"version": -1, "configs": {}}', encoding="utf-8")
     assert autotune.load_table(str(stale)) == {}
 
 
+def test_peaks_are_keyed_by_device_kind():
+    """Candidate ranking reads published peaks for the device kind, and a
+    device without a row is an error, not a default."""
+    from repro.launch import roofline
+    assert roofline.peaks("TPU v5 lite").hbm_bytes_s == 819e9
+    assert autotune.candidate_configs(64, "TPU v5 lite", max_candidates=2)
+    with pytest.raises(roofline.UnknownDeviceError):
+        autotune.candidate_configs(64, "cpu")
+
+
 def test_version_mismatch_invalidates(tmp_path):
     path = str(tmp_path / "t.json")
-    autotune.record("xla", 64, autotune.KernelConfig(), path=path)
+    autotune.record("xla", 64, autotune.KernelConfig(), "cpu", path=path)
     import json
     with open(path) as f:
         data = json.load(f)
     data["version"] = autotune.TABLE_VERSION + 1
     with open(path, "w") as f:
         json.dump(data, f)
-    assert autotune.lookup("xla", 64, path=path) is None
+    assert autotune.lookup("xla", 64, "cpu", path=path) is None
 
 
 # -- the public cache surface (the satellite the benchmarks now use) --------

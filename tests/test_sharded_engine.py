@@ -8,6 +8,8 @@ the stack+sum fallback (identical integers to the psum path), and the CI
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` so every shard owns
 a distinct device."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -234,3 +236,76 @@ class TestDriverShardsValidation:
         wrong = ShardPlan.make(sm.n_segments + 5, shards=2)
         with pytest.raises(ValueError, match="segments"):
             RelationEngine(pre, RELS, shard_plan=wrong)
+
+
+class TestCompileOnDevices:
+    def test_the_call_reuses_the_devices_executables(self):
+        """What ``compile_on_devices`` compiles is what the later call runs:
+        the call pays no compile of its own."""
+        import jax
+        import jax.numpy as jnp
+        from repro.distributed.sharding import compile_on_devices
+
+        f = jax.jit(lambda x, k: jnp.sort(x * k), static_argnums=1)
+        # committed to its device, as the sharded engine's tables are
+        x = jax.device_put(jnp.arange(24, dtype=jnp.int32), jax.devices()[0])
+        compile_on_devices(f, (x, 3), jax.devices())
+        seen = []
+
+        def listener(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                seen.append(duration)
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            np.testing.assert_array_equal(np.asarray(f(x, 3)),
+                                          np.arange(24) * 3)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listener)
+        assert seen == []
+
+
+def test_four_devices_one_pool_each_and_one_compile_per_kernel_shape():
+    """On four host devices the sharded engine keeps each shard's blocks on
+    its own device, exchanges by psum and matches shards=1 bit for bit;
+    each relation kernel shape compiles exactly once on each device."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent("""
+        import collections, logging, re, sys
+        sys.path.insert(0, %r)
+        import jax
+        jax.config.update("jax_log_compiles", True)
+        names = collections.Counter()
+        class Count(logging.Handler):
+            def emit(self, rec):
+                m = re.search(r"Finished XLA compilation of (\\S+)",
+                              rec.getMessage())
+                if m:
+                    names[m.group(1)] += 1
+        logging.getLogger("jax").addHandler(Count())
+        import chip_smoke
+        from repro.core.engine import RelationEngine
+        chip_smoke.check_sharded((16, 6, 6), seed=1, shards=4, log=print)
+        jax.clear_caches()
+        names.clear()
+        sm, pre, rank, _ = chip_smoke.build((16, 6, 6), 1)
+        eng = RelationEngine(pre, list(chip_smoke.RELS), shards=4)
+        chip_smoke.run_drivers(eng, pre, rank, "device", "count", print)
+        print("kernel shapes", len(eng._compiled_on_all),
+              "compiles", names["jit(_relation_block_fused)"])
+    """ % repo)
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "bit-identical to shards=1" in out.stdout
+    assert re.search(r"exchange psum=[1-9]\d* stack=0", out.stdout)
+    shapes, compiles = map(int, re.search(
+        r"kernel shapes (\d+) compiles (\d+)", out.stdout).groups())
+    assert shapes > 0 and compiles == 4 * shapes, out.stdout
