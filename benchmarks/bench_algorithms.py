@@ -113,7 +113,7 @@ def bench(algo: str, relations, datasets, structures=STRUCTURES,
                     "algo": algo, "dataset": name, "structure": kind,
                     "t_init": t_init, "t_pre": t_pre, "t_algo": t_algo,
                     "t_sync": stats.t_sync if stats else 0.0,
-                    "t_kernel": stats.t_kernel if stats else 0.0,
+                    "t_dispatch": stats.t_dispatch if stats else 0.0,
                     "requests": stats.requests if stats else 0,
                     "devpool_hits": stats.devpool_hits if stats else 0,
                     "devpool_uploads": stats.devpool_uploads if stats else 0,

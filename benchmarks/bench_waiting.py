@@ -32,11 +32,11 @@ from .bench_algorithms import CP_RELS, DG_RELS, MS_RELS
 
 
 def _fmt(st, total):
-    wait = st.t_enqueue + st.t_queue + st.t_prepare + st.t_kernel \
+    wait = st.t_enqueue + st.t_queue + st.t_prepare + st.t_dispatch \
         + st.t_sync + st.t_integrate
     return (f"total_s={total:.3f};wait_s={wait:.3f};"
             f"push_s={st.t_enqueue:.4f};queue_s={st.t_queue:.4f};"
-            f"prep_s={st.t_prepare:.4f};dispatch_s={st.t_kernel:.4f};"
+            f"prep_s={st.t_prepare:.4f};dispatch_s={st.t_dispatch:.4f};"
             f"sync_s={st.t_sync:.4f};integrate_s={st.t_integrate:.4f};"
             f"requests={st.requests};hits={st.cache_hits};"
             f"inflight_hits={st.inflight_hits};misses={st.cache_misses}")
@@ -95,7 +95,7 @@ def run(quick: bool = True) -> List[str]:
             # overlap_ok iff the async consumer waited strictly less than
             # that, i.e. kernel execution was (partially) hidden behind
             # consumer work — the paper's Fig. 2(b) claim.
-            kern = stats["blocking"].t_kernel + stats["blocking"].t_sync
+            kern = stats["blocking"].t_dispatch + stats["blocking"].t_sync
             hidden = kern - stats["async"].t_sync
             rows.append(common.row(
                 f"waiting/{algo}/{dataset}/consumers{w}/overlap", hidden,

@@ -174,7 +174,7 @@ def _engine_line(eng) -> str:
             f"requests={s.requests} devpool_hits={s.devpool_hits} "
             f"devpool_uploads={s.devpool_uploads} "
             f"completion_queries={s.completion_queries} "
-            f"t_sync={s.t_sync:.3f}s t_kernel={s.t_kernel:.3f}s")
+            f"t_sync={s.t_sync:.3f}s t_dispatch={s.t_dispatch:.3f}s")
 
 
 def check_main_path(grid=GRID_1CHIP, seed: int = 0, warm: bool = False,

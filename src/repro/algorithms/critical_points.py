@@ -27,6 +27,7 @@ import numpy as np
 from ..core.adjacency import complete_adjacency
 from ..core.mesh import _FACE_COMBOS
 from ..core.scheduler import run_partitioned, segment_batches
+from ..core.spans import spanned
 from ..kernels import ops
 from . import consume
 
@@ -201,6 +202,7 @@ def _classify_batch(
     return t
 
 
+@spanned("driver.critical_points")
 def critical_points(
     ds,                      # RelationEngine / ExplicitTriangulation / ...
     pre,

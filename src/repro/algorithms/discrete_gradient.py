@@ -27,6 +27,7 @@ import numpy as np
 
 from ..core.adjacency import complete_adjacency
 from ..core.scheduler import run_partitioned, segment_batches
+from ..core.spans import spanned
 from ..kernels import ops
 from . import consume
 
@@ -318,6 +319,7 @@ def _download_device_batch(cb, degs, out):
             np.asarray(crit)[:n], de, df, dt)
 
 
+@spanned("driver.discrete_gradient")
 def discrete_gradient(
     ds, pre, rank: np.ndarray, batch_segments: int = 8,
     audit: bool = False, consumer: str = "auto",

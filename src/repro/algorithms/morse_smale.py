@@ -42,6 +42,7 @@ import numpy as np
 
 from ..core.adjacency import complete_adjacency
 from ..core.scheduler import run_collect, run_partitioned, segment_batches
+from ..core.spans import span, spanned
 from ..kernels import ops
 from . import consume
 from .discrete_gradient import GradientField
@@ -87,6 +88,7 @@ def _pointer_jump(succ: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.fori_loop(0, rounds, body, succ)
 
 
+@spanned("driver.ms.successors")
 def _gather_ft(ds, pre, batch_segments: int = 16,
                workers: int = 1, plan=None) -> np.ndarray:
     """Assemble the global FT table (nf, 2) through the data structure —
@@ -120,6 +122,7 @@ def _gather_ft(ds, pre, batch_segments: int = 16,
     return ft
 
 
+@spanned("driver.ms.cofacets")
 def _cofacet_rows(ds, pre, face_ids, batch_segments: int = 16,
                   mode: str = "host", workers: int = 1,
                   plan=None) -> np.ndarray:
@@ -226,6 +229,7 @@ def _across_successors(M: jnp.ndarray,   # (p, deg) completed TT, -1 pad
     return nxt, has
 
 
+@spanned("driver.ms.successors")
 def _ascending_successors_tt(ds, pre, grad: GradientField,
                              batch: int, mode: str = "host",
                              workers: int = 1) -> np.ndarray:
@@ -265,6 +269,7 @@ def _ascending_successors_tt(ds, pre, grad: GradientField,
     return succ
 
 
+@spanned("driver.morse_smale")
 def morse_smale(ds, pre, grad: GradientField,
                 batch_segments: int = 16,
                 adjacency: str = "auto",
@@ -294,13 +299,14 @@ def morse_smale(ds, pre, grad: GradientField,
         adjacency == "auto" and _supports_completion(ds, "TT", "FT"))
 
     # ---- descending: vertex successor through v->e pairs -------------------
-    e = grad.pair_v2e                      # (nv,)
-    other = np.where(e >= 0,
-                     np.where(E[np.maximum(e, 0), 0] == np.arange(nv),
-                              E[np.maximum(e, 0), 1],
-                              E[np.maximum(e, 0), 0]),
-                     np.arange(nv))
-    dest_min = np.asarray(_pointer_jump(jnp.asarray(other)))
+    with span("driver.ms.descending"):
+        e = grad.pair_v2e                      # (nv,)
+        other = np.where(e >= 0,
+                         np.where(E[np.maximum(e, 0), 0] == np.arange(nv),
+                                  E[np.maximum(e, 0), 1],
+                                  E[np.maximum(e, 0), 0]),
+                         np.arange(nv))
+        dest_min = np.asarray(_pointer_jump(jnp.asarray(other)))
 
     # ---- ascending: tet successor through t->f pairs -----------------------
     s2 = np.nonzero(grad.crit_f)[0]
@@ -322,22 +328,24 @@ def morse_smale(ds, pre, grad: GradientField,
         succ_t = np.where((f >= 0) & (nxt >= 0), nxt, me)
         cof_s2 = ft[s2]
     # paths that exit through a boundary face stall on a non-critical tet
-    dest_t = np.asarray(_pointer_jump(jnp.asarray(succ_t)))
-    reached_max = grad.crit_t[dest_t]
-    dest_max = np.where(reached_max, dest_t, -1)
+    with span("driver.ms.ascending_jump"):
+        dest_t = np.asarray(_pointer_jump(jnp.asarray(succ_t)))
+        reached_max = grad.crit_t[dest_t]
+        dest_max = np.where(reached_max, dest_t, -1)
 
     # ---- separatrices -------------------------------------------------------
-    s1 = np.nonzero(grad.crit_e)[0]
-    ends1 = np.stack([s1, dest_min[E[s1, 0]], dest_min[E[s1, 1]]], axis=1) \
-        if len(s1) else np.zeros((0, 3), np.int64)
-
-    if len(s2):
-        c0, c1 = cof_s2[:, 0], cof_s2[:, 1]
-        m0 = np.where(c0 >= 0, dest_max[np.maximum(c0, 0)], -1)
-        m1 = np.where(c1 >= 0, dest_max[np.maximum(c1, 0)], -1)
-        ends2 = np.stack([s2, m0, m1], axis=1)
-    else:
-        ends2 = np.zeros((0, 3), np.int64)
+    with span("driver.ms.separatrices"):
+        s1 = np.nonzero(grad.crit_e)[0]
+        ends1 = (np.stack([s1, dest_min[E[s1, 0]], dest_min[E[s1, 1]]],
+                          axis=1)
+                 if len(s1) else np.zeros((0, 3), np.int64))
+        if len(s2):
+            c0, c1 = cof_s2[:, 0], cof_s2[:, 1]
+            m0 = np.where(c0 >= 0, dest_max[np.maximum(c0, 0)], -1)
+            m1 = np.where(c1 >= 0, dest_max[np.maximum(c1, 0)], -1)
+            ends2 = np.stack([s2, m0, m1], axis=1)
+        else:
+            ends2 = np.zeros((0, 3), np.int64)
 
     return MSComplex(dest_min=dest_min, dest_max=dest_max,
                      saddle1_ends=ends1, saddle2_ends=ends2)

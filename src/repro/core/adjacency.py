@@ -35,7 +35,10 @@ k+1 before executing chunk k), which is how the algorithm drivers request
 completed adjacency. Both paths are bit-identical for any chunking.
 Completion work is accounted in ``EngineStats`` (``completion_queries``,
 ``completion_fanout_blocks``, ``completion_raw_neighbors`` /
-``completion_neighbors`` and the derived ``completion_dedup_ratio``).
+``completion_neighbors`` and the derived ``completion_dedup_ratio``), and
+traced as ``completion.*`` spans (``core/spans.py``): the whole call, each
+chunk's plan, each execute, and each execute's first wait on the device
+(``completion.width_check``).
 
 :func:`complete_adjacency_scalar` is the one-simplex-at-a-time reference kept
 for the A/B benchmark (``benchmarks/bench_adjacency.py``) and the
@@ -53,6 +56,7 @@ import numpy as np
 
 from ..kernels import ops
 from .engine import RelationEngine, RelationWidthError
+from .spans import span, spanned
 
 ADJ_COMPLETION_RELATIONS = ("EE", "FF", "TT")
 
@@ -88,6 +92,7 @@ def _boundary_owner_segments(eng: RelationEngine, relation: str,
     return pre.owner_segment("F", tf).astype(np.int64)
 
 
+@spanned("completion.plan")
 def plan_completion(eng: RelationEngine, relation: str,
                     ids: Sequence[int], prefetch: bool = True
                     ) -> CompletionPlan:
@@ -137,6 +142,7 @@ def plan_completion(eng: RelationEngine, relation: str,
                           pair_row.astype(np.int32), segments)
 
 
+@spanned("completion.execute")
 def execute_completion(eng: RelationEngine, plan: CompletionPlan
                        ) -> Tuple[np.ndarray, np.ndarray]:
     """Gather + union the planned rows into padded ``(M, L)`` arrays.
@@ -205,6 +211,7 @@ _pow2 = ops.bucket_rows
 
 
 # contract: device-resident
+@spanned("completion.execute")
 def execute_completion_device(eng: RelationEngine, plan: CompletionPlan,
                               out: str = "host"
                               ) -> Tuple[np.ndarray, np.ndarray]:
@@ -276,33 +283,41 @@ def execute_completion_device(eng: RelationEngine, plan: CompletionPlan,
         jnp.asarray(pair_gid), jnp.asarray(pair_at),
         deg_out=deg, backend=eng.backend, inv_key=inv_key, n_global=n_glob)
 
-    eng.stat_bump(completion_raw_neighbors=int(raw),
-                  completion_neighbors=int(kept))
-    if out == "dev":
-        # device-resident consumers take the padded (n, deg) rows as-is;
-        # the overflow check costs one scalar reduce, not a block download
-        worst = int(jnp.max(L_dev[:n])) if n else 0
-        if worst > deg:
-            raise RelationWidthError(
-                f"completed {relation!r} row has {worst} neighbours but the "
-                f"preallocated width is deg[{relation!r}]={deg}; construct "
-                f"the engine with deg={{{relation!r}: {worst}}} (or larger).")
-        return M_dev[:n], L_dev[:n]
-    # the batch's documented ONE host round trip (DESIGN.md §6):
-    Mh = np.asarray(M_dev)[:n]          # contract: host-roundtrip
-    Lh = np.asarray(L_dev)[:n]          # contract: host-roundtrip
-    worst = int(Lh.max()) if n else 0
+    return _checked_rows(eng, relation, n, M_dev, L_dev, raw, kept, out)
+
+
+def _checked_rows(eng: RelationEngine, relation: str, n: int, M_dev, L_dev, raw,
+            kept, out: str):
+    """The tail of both device execute arms: count the chunk's neighbours,
+    check the widest row against ``deg[relation]``, and return the rows on
+    the device (``out="dev"``) or as host arrays trimmed to the widest row.
+    The first device read here waits for the chunk's gather, so the reads
+    run inside the ``completion.width_check`` span."""
+    deg = eng.deg[relation]
+    with span("completion.width_check"):
+        eng.stat_bump(completion_raw_neighbors=int(raw),
+                      completion_neighbors=int(kept))
+        if out == "dev":
+            # device-resident consumers take the padded (n, deg) rows
+            # as-is; the overflow check costs one scalar reduce, not a
+            # block download
+            worst = int(jnp.max(L_dev[:n])) if n else 0
+        else:
+            # the chunk's documented ONE host round trip (DESIGN.md §6)
+            Mh = np.asarray(M_dev)[:n]
+            Lh = np.asarray(L_dev)[:n]
+            worst = int(Lh.max()) if n else 0
     if worst > deg:
         raise RelationWidthError(
             f"completed {relation!r} row has {worst} neighbours but the "
             f"preallocated width is deg[{relation!r}]={deg}; construct the "
             f"engine with deg={{{relation!r}: {worst}}} (or larger).")
-    width = max(worst, 1)
-    M = Mh[:, :width].astype(np.int64)
-    L = Lh.astype(np.int32)
-    return M, L
+    if out == "dev":
+        return M_dev[:n], L_dev[:n]
+    return Mh[:, :max(worst, 1)].astype(np.int64), Lh.astype(np.int32)
 
 
+@spanned("completion.execute")
 def execute_completion_sharded(eng: RelationEngine, plan: CompletionPlan,
                                out: str = "host"
                                ) -> Tuple[np.ndarray, np.ndarray]:
@@ -402,30 +417,10 @@ def execute_completion_sharded(eng: RelationEngine, plan: CompletionPlan,
     M_dev, L_dev, raw, kept = _cg.union_pairs(
         cand, clen, pair_gid_dev, jnp.asarray(pair_at), deg)
 
-    eng.stat_bump(completion_raw_neighbors=int(raw),
-                  completion_neighbors=int(kept))
-    if out == "dev":
-        worst = int(jnp.max(L_dev[:n])) if n else 0
-        if worst > deg:
-            raise RelationWidthError(
-                f"completed {relation!r} row has {worst} neighbours but the "
-                f"preallocated width is deg[{relation!r}]={deg}; construct "
-                f"the engine with deg={{{relation!r}: {worst}}} (or larger).")
-        return M_dev[:n], L_dev[:n]
-    Mh = np.asarray(M_dev)[:n]          # the chunk's ONE host round trip
-    Lh = np.asarray(L_dev)[:n]
-    worst = int(Lh.max()) if n else 0
-    if worst > deg:
-        raise RelationWidthError(
-            f"completed {relation!r} row has {worst} neighbours but the "
-            f"preallocated width is deg[{relation!r}]={deg}; construct the "
-            f"engine with deg={{{relation!r}: {worst}}} (or larger).")
-    width = max(worst, 1)
-    M = Mh[:, :width].astype(np.int64)
-    L = Lh.astype(np.int32)
-    return M, L
+    return _checked_rows(eng, relation, n, M_dev, L_dev, raw, kept, out)
 
 
+@spanned("completion.complete")
 def complete_adjacency(
     eng: RelationEngine, relation: str, ids: Sequence[int],
     batch: Optional[int] = None, path: Optional[str] = None,

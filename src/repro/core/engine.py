@@ -25,9 +25,9 @@ recorded in an **in-flight futures table** keyed by ``(relation, segment)``.
     kernels in flight at once), returning immediately.
   - :meth:`get` / :meth:`get_batch` block only when they read a block that
     is still computing; the wait is accounted in ``stats.t_sync`` (the
-    paper's Fig. 10 "waiting" metric). ``stats.t_kernel`` records only the
-    host-side dispatch cost, so ``t_sync`` vs ``t_kernel`` quantifies how
-    much of the kernel execution was hidden behind consumer work.
+    paper's Fig. 10 "waiting" metric). ``stats.t_dispatch`` records only
+    the host-side dispatch cost, so ``t_sync`` vs ``t_dispatch`` quantifies
+    how much of the kernel execution was hidden behind consumer work.
   - A segment is never produced twice: requests are de-duplicated against
     the cache, the in-flight table, and the pending queues.
 
@@ -61,7 +61,10 @@ launch wait on the condition variable until ``launch.done``. Consequences:
 
 The engine also keeps the paper's accounting (Table 5/6/7): per-phase wait
 times (enqueue / queue / prepare / kernel dispatch / sync / integrate) and
-cache statistics.
+cache statistics. The prepare, dispatch, sync and integrate times are the
+host intervals of the ``engine.dispatch`` / ``engine.sync`` /
+``engine.integrate`` spans (``core/spans.py``), so a profiler trace shows
+each one on the device's clock.
 """
 
 from __future__ import annotations
@@ -96,6 +99,7 @@ from .segtables import (
     Preconditioned,
     RELATION_TABLES,
 )
+from .spans import span, spanned
 
 
 @dataclasses.dataclass
@@ -159,7 +163,7 @@ class EngineStats:
     t_enqueue: float = 0.0
     t_queue: float = 0.0
     t_prepare: float = 0.0
-    t_kernel: float = 0.0    # host-side kernel DISPATCH time only
+    t_dispatch: float = 0.0  # host-side kernel dispatch time only
     t_sync: float = 0.0      # time the consumer waited on in-flight results
     t_integrate: float = 0.0
 
@@ -211,7 +215,7 @@ class StatsHost:
     # lands on the global/worker stats via _bump, so the §8 worker
     # invariant is untouched; docs/DESIGN.md §9)
     _SHARD_FIELDS = ("kernel_launches", "segments_produced",
-                     "devpool_hits", "devpool_uploads", "t_kernel")
+                     "devpool_hits", "devpool_uploads", "t_dispatch")
 
     def _init_stats(self) -> None:
         self.stats = EngineStats()
@@ -241,6 +245,25 @@ class StatsHost:
             ws = self.worker_stats[w] = EngineStats()
         self.stats.bump(**deltas)
         ws.bump(**deltas)
+
+    @contextlib.contextmanager
+    def _timed(self, name: str, counter: str, relation: Optional[str] = None,
+               shard: Optional[int] = None):
+        # contract: holds-lock
+        """Run the block inside ``span(name)`` and add its host interval to
+        the ``counter`` phase time (and to shard ``shard``'s, where given),
+        so with the profiler on a ``t_*`` counter is the sum of its spans.
+        ``relation`` is the span's one argument. The caller holds
+        ``self._cond`` when the block starts and when it ends."""
+        t0 = time.perf_counter()
+        with (span(name, relation=relation) if relation else span(name)):
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                self._bump(**{counter: dt})
+                if shard is not None:
+                    self._bump_shard(shard, **{counter: dt})
 
     def stat_bump(self, **deltas) -> None:
         """Thread-safe counter update for out-of-engine accounting (the
@@ -280,7 +303,7 @@ class StatsHost:
     def merged_shard_stats(self) -> EngineStats:
         """Deterministic merge of the per-shard producer breakdown (sorted
         shard order); equals ``stats`` on the producer counters
-        (``_SHARD_FIELDS``): ints exactly, ``t_kernel`` up to float
+        (``_SHARD_FIELDS``): ints exactly, ``t_dispatch`` up to float
         summation order. The sharded-engine tests assert it, and per-shard
         ``segments_produced`` proves no segment was produced on more than
         one shard."""
@@ -393,6 +416,7 @@ class RelationEngine(StatsHost):
     + docs/DESIGN.md §8): every public consumer method acquires the engine
     lock exactly once; internal ``_``-prefixed steps assume it is held."""
 
+    @spanned("engine.init")
     def __init__(
         self,
         pre: Preconditioned,
@@ -724,6 +748,7 @@ class RelationEngine(StatsHost):
             M, L, i = self._dev_entry(relation, int(segment))
         return (M, L) if i is None else (M[i], L[i])
 
+    @spanned("consumer.read_dev")
     def get_full_dev_batch(self, relation: str, segments: Sequence[int],
                            pad_to: Optional[int] = None
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -839,12 +864,13 @@ class RelationEngine(StatsHost):
                         pooled = False
                 # uploads land on the segment's owning shard device, so the
                 # per-shard pool really bounds that device's memory
-                if self._multi_dev:
-                    d = self.shard_plan.devices[shard]
-                    ent = (jax.device_put(Mh, d), jax.device_put(Lh, d),
-                           None)
-                else:
-                    ent = (jnp.asarray(Mh), jnp.asarray(Lh), None)
+                with span("consumer.upload"):
+                    if self._multi_dev:
+                        d = self.shard_plan.devices[shard]
+                        ent = (jax.device_put(Mh, d), jax.device_put(Lh, d),
+                               None)
+                    else:
+                        ent = (jnp.asarray(Mh), jnp.asarray(Lh), None)
                 if pooled:
                     self._dev_pool.put(key, *ent)
                 self._bump(devpool_uploads=1)
@@ -854,6 +880,7 @@ class RelationEngine(StatsHost):
         self._bump_shard(shard, devpool_hits=1)
         return ent
 
+    @spanned("consumer.read_dev")
     def get_full_dev_many(self, relations: Sequence[str],
                           segments: Sequence[int],
                           cols: Optional[Dict[str, int]] = None
@@ -1122,13 +1149,10 @@ class RelationEngine(StatsHost):
             # launch always syncs under one continuous lock hold, so the
             # MRU put guarantees the re-read hits and the loop terminates
         M, L, n_rows = hit
-        t0 = time.perf_counter()
         # cached blocks are host ndarrays (see _integrate), so the views
         # need no conversion — and converting under the lock would trip
         # contractcheck's blocking-under-lock rule
-        out = (M, L) if full else (M[:n_rows], L[:n_rows])
-        self._bump(t_integrate=time.perf_counter() - t0)
-        return out
+        return (M, L) if full else (M[:n_rows], L[:n_rows])
 
     def _drain(self, relations: Optional[Sequence[str]] = None) -> None:
         # contract: holds-lock
@@ -1185,23 +1209,35 @@ class RelationEngine(StatsHost):
         FAILED (:meth:`_fail_launch`): waiters wake immediately instead of
         hanging on the condvar, the breaker records the failure, and
         callers re-dispatch the segments (degrading to the host arm once
-        the breaker opens)."""
+        the breaker opens).
+
+        Every wait, a waiter's included, is one ``engine.sync`` span and
+        lands in ``t_sync``; the integration after it is its own
+        ``engine.integrate`` span."""
         if launch.done or launch.error is not None:
             return
-        t0 = time.perf_counter()
+        with self._timed("engine.sync", "t_sync", relation=launch.relation):
+            synced = self._await_launch(launch)
+        if synced is None:            # syncer failed: take over the sync
+            self._sync(launch)
+        elif synced:
+            self._integrate(launch)
+            self._cond.notify_all()
+
+    def _await_launch(self, launch: _Launch) -> Optional[bool]:
+        # contract: holds-lock
+        """The wait of :meth:`_sync`. Returns True where this thread synced
+        the launch and has to integrate it; False where nothing is left to
+        do (another syncer integrated it, or the launch failed and the
+        caller re-dispatches); None where the syncer this thread waited on
+        gave up without integrating."""
         if launch.syncing:
             while launch.syncing and not launch.done \
                     and launch.error is None:
                 self._cond.wait()   # contract: syncer-handoff
-            if launch.error is not None:
-                # the syncer failed the launch (watchdog / device loss):
-                # account the wait and let the caller re-dispatch
-                self._bump(t_sync=time.perf_counter() - t0)
-                return
-            if not launch.done:       # syncer failed: take over the sync
-                return self._sync(launch)
-            self._bump(t_sync=time.perf_counter() - t0)
-            return
+            if launch.error is None and not launch.done:
+                return None
+            return False
         launch.syncing = True
         try:
             while True:
@@ -1225,16 +1261,12 @@ class RelationEngine(StatsHost):
                 if launch.sync_attempts >= self._fault_policy.max_attempts:
                     self._fail_launch(launch, timed_out)
                     self._breaker_failure(launch.relation, timed_out)
-                    self._bump(t_sync=time.perf_counter() - t0)
-                    return
+                    return False
                 self._bump(retries=1)
         finally:
             launch.syncing = False
             self._cond.notify_all()
-        self._bump(t_sync=time.perf_counter() - t0)
-        if launch.error is None:
-            self._integrate(launch)
-        self._cond.notify_all()
+        return launch.error is None
 
     def _device_wait(self, launch: _Launch) -> None:
         """Device wait for one launch, called by the syncer with the engine
@@ -1406,43 +1438,45 @@ class RelationEngine(StatsHost):
         # contract: holds-lock
         if launch.done or launch.error is not None:
             return
-        t0 = time.perf_counter()
-        # One host copy per launch while the results are known-ready. Cached
-        # blocks must be host arrays, not device views: a lazy device slice
-        # would queue behind later in-flight kernels on the single device
-        # stream, so reads of batch k would stall on batch k+1's launch.
-        Mh = np.asarray(launch.M)   # contract: syncer-handoff (ready)
-        Lh = np.asarray(launch.L)   # contract: syncer-handoff (ready)
-        # Preallocated-width contract (paper §4.6): L is the TRUE row count
-        # while M holds at most deg entries, so L > deg means the compaction
-        # silently dropped neighbours. Fail loudly with the fix.
-        worst = int(Lh.max()) if Lh.size else 0
-        deg = self.deg[launch.relation]
-        if worst > deg:
-            raise RelationWidthError(
-                f"relation {launch.relation!r} produced a row with {worst} "
-                f"entries but the preallocated width is "
-                f"deg[{launch.relation!r}]={deg}; the compacted M row would "
-                f"silently drop neighbours. Construct the engine with "
-                f"deg={{{launch.relation!r}: {worst}}} (or larger).")
-        # Reverse order so the explicitly requested segments (batch front)
-        # are most-recently-used and cannot be LRU-evicted by their own
-        # lookahead when the cache is small.
-        for i, s in reversed(list(enumerate(launch.segments))):
-            self._inflight.pop((launch.relation, s), None)
-            self.cache.put((launch.relation, s),
-                           (Mh[i], Lh[i], launch.n_rows[i]))
-            # device pool: keep the still-device-resident rows addressable
-            # for get_full_dev (holds a reference to the launch arrays).
-            # Degraded host-arm launches hold numpy arrays — never pooled;
-            # device reads of their blocks go through the counted upload
-            # path in _dev_entry instead.
-            if not launch.host:
-                self._dev_pool.put((launch.relation, s),
-                                   launch.M, launch.L, i)
-        launch.done = True
-        self._bump(evictions=self.cache.evictions - self.stats.evictions,
-                   t_integrate=time.perf_counter() - t0)
+        with self._timed("engine.integrate", "t_integrate",
+                         relation=launch.relation):
+            # One host copy per launch while the results are known-ready.
+            # Cached blocks must be host arrays, not device views: a lazy
+            # device slice would queue behind later in-flight kernels on the
+            # single device stream, so reads of batch k would stall on batch
+            # k+1's launch.
+            Mh = np.asarray(launch.M)   # contract: syncer-handoff (ready)
+            Lh = np.asarray(launch.L)   # contract: syncer-handoff (ready)
+            # Preallocated-width contract (paper §4.6): L is the TRUE row count
+            # while M holds at most deg entries, so L > deg means the
+            # compaction silently dropped neighbours. Fail loudly with the fix.
+            worst = int(Lh.max()) if Lh.size else 0
+            deg = self.deg[launch.relation]
+            if worst > deg:
+                raise RelationWidthError(
+                    f"relation {launch.relation!r} produced a row with "
+                    f"{worst} entries but the preallocated width is "
+                    f"deg[{launch.relation!r}]={deg}; the compacted M row "
+                    f"would silently drop neighbours. Construct the engine "
+                    f"with deg={{{launch.relation!r}: {worst}}} (or larger).")
+            # Reverse order so the explicitly requested segments (batch front)
+            # are most-recently-used and cannot be LRU-evicted by their own
+            # lookahead when the cache is small.
+            for i, s in reversed(list(enumerate(launch.segments))):
+                self._inflight.pop((launch.relation, s), None)
+                self.cache.put((launch.relation, s),
+                               (Mh[i], Lh[i], launch.n_rows[i]))
+                # device pool: keep the still-device-resident rows addressable
+                # for get_full_dev (holds a reference to the launch arrays).
+                # Degraded host-arm launches hold numpy arrays — never pooled;
+                # device reads of their blocks go through the counted upload
+                # path in _dev_entry instead.
+                if not launch.host:
+                    self._dev_pool.put((launch.relation, s),
+                                       launch.M, launch.L, i)
+            launch.done = True
+            self._bump(evictions=self.cache.evictions
+                       - self.stats.evictions)
 
     def _lookahead_segments(self, relation: str, batch: List[int]) -> List[int]:
         # contract: holds-lock
@@ -1485,36 +1519,36 @@ class RelationEngine(StatsHost):
         tables at shard-local indices — on a multi-device plan the whole
         launch therefore runs and lands on the owning shard's device
         (docs/DESIGN.md §9)."""
-        t0 = time.perf_counter()
-        q = self.queues[relation]
-        batch: List[int] = []
-        shard = -1
-        deferred: List[int] = []
-        while q and len(batch) < self.batch_max:
-            s = q.pop(0)
-            # stale entry: produced since it was queued
-            if (relation, s) in self.cache or (relation, s) in self._inflight:
-                continue
-            if shard < 0:
-                shard = int(self._seg_shard[s])
-            elif int(self._seg_shard[s]) != shard:
-                deferred.append(s)
-                continue
-            batch.append(s)
-        if deferred:
-            q[0:0] = deferred
-        if not batch:
-            self._bump(t_prepare=time.perf_counter() - t0)
-            return None
-        look = self._lookahead_segments(relation, batch)
-        room = self.batch_max - len(batch)
-        batch = batch + look[:room]
-        if look[room:]:
-            # the launch is capped at batch_max; overflow lookahead is
-            # requeued so proactive production continues in later launches
-            qs = set(q)
-            q.extend(s for s in look[room:] if s not in qs)
-        self._bump(t_prepare=time.perf_counter() - t0)
+        with self._timed("engine.dispatch", "t_prepare", relation=relation):
+            q = self.queues[relation]
+            batch: List[int] = []
+            shard = -1
+            deferred: List[int] = []
+            while q and len(batch) < self.batch_max:
+                s = q.pop(0)
+                # stale entry: produced since it was queued
+                if ((relation, s) in self.cache
+                        or (relation, s) in self._inflight):
+                    continue
+                if shard < 0:
+                    shard = int(self._seg_shard[s])
+                elif int(self._seg_shard[s]) != shard:
+                    deferred.append(s)
+                    continue
+                batch.append(s)
+            if deferred:
+                q[0:0] = deferred
+            if not batch:
+                return None
+            look = self._lookahead_segments(relation, batch)
+            room = self.batch_max - len(batch)
+            batch = batch + look[:room]
+            if look[room:]:
+                # the launch is capped at batch_max; overflow lookahead is
+                # requeued so proactive production continues in later
+                # launches
+                qs = set(q)
+                q.extend(s for s in look[room:] if s not in qs)
         return self._launch(relation, batch, shard)
 
     def _launch(self, relation: str, batch: List[int], shard: int
@@ -1602,27 +1636,25 @@ class RelationEngine(StatsHost):
                                               shard)
             if exc is not None:
                 raise exc
-        t0 = time.perf_counter()
-        # pad the launch to a power-of-two bucket (duplicating the last
-        # segment) so jit sees O(log batch_max) shapes, not one per drain
-        b_pad = ops.bucket_rows(len(batch), self.bucket_floor)
-        padded = batch + [batch[-1]] * (b_pad - len(batch))
-        lo = self.shard_plan.bounds[shard]
-        segs = jnp.asarray(np.asarray(padded, dtype=np.int32) - lo)
-
         kx, ky = RELATION_TABLES[relation]
         deg = self.deg[relation]
         nvl = self.tables.NV
         tabs = self._shard_tables[shard]
-        if relation == "VV":
-            tabX = jnp.take(tabs["T_local"], segs, axis=0)
-            tabY = tabX
-            colg = jnp.take(tabs["LV_global"], segs, axis=0)
-        else:
-            tabX = self._table_dev(kx, segs, tabs)
-            tabY = self._table_dev(ky, segs, tabs)
-            colg = jnp.take(tabs[_GLOBAL_NAME[ky]], segs, axis=0)
-        self._bump(t_prepare=time.perf_counter() - t0)
+        with self._timed("engine.dispatch", "t_prepare", relation=relation):
+            # pad the launch to a power-of-two bucket (duplicating the last
+            # segment) so jit sees O(log batch_max) shapes, not one per drain
+            b_pad = ops.bucket_rows(len(batch), self.bucket_floor)
+            padded = batch + [batch[-1]] * (b_pad - len(batch))
+            lo = self.shard_plan.bounds[shard]
+            segs = jnp.asarray(np.asarray(padded, dtype=np.int32) - lo)
+            if relation == "VV":
+                tabX = jnp.take(tabs["T_local"], segs, axis=0)
+                tabY = tabX
+                colg = jnp.take(tabs["LV_global"], segs, axis=0)
+            else:
+                tabX = self._table_dev(kx, segs, tabs)
+                tabY = self._table_dev(ky, segs, tabs)
+                colg = jnp.take(tabs[_GLOBAL_NAME[ky]], segs, axis=0)
 
         # a kernel shape new to a multi-device engine compiles on every
         # shard device at once, not once per device as each shard meets it
@@ -1632,16 +1664,15 @@ class RelationEngine(StatsHost):
             self._compiled_on_all.add(key)
             compile_on = tuple({d.id: d for d in
                                 self.shard_plan.devices}.values())
-        t1 = time.perf_counter()
-        M, L = ops.relation_block(
-            relation, tabX, tabY, colg, nvl, deg=deg, backend=self.backend,
-            block_x=self.block_x, block_y=self.block_y,
-            vv_block=self.vv_block, assembly=self.assembly,
-            compile_on=compile_on)
-        dt = time.perf_counter() - t1
-        self._bump(t_kernel=dt, kernel_launches=1,
-                   segments_produced=len(batch))
-        self._bump_shard(shard, t_kernel=dt, kernel_launches=1,
+        with self._timed("engine.dispatch", "t_dispatch", relation=relation,
+                         shard=shard):
+            M, L = ops.relation_block(
+                relation, tabX, tabY, colg, nvl, deg=deg,
+                backend=self.backend, block_x=self.block_x,
+                block_y=self.block_y, vv_block=self.vv_block,
+                assembly=self.assembly, compile_on=compile_on)
+        self._bump(kernel_launches=1, segments_produced=len(batch))
+        self._bump_shard(shard, kernel_launches=1,
                          segments_produced=len(batch))
 
         n_int, _ = self.tables.counts(kx if relation != "VV" else "V")
@@ -1677,26 +1708,25 @@ class RelationEngine(StatsHost):
         ``degraded_*`` counters record the detour. Host launches are never
         device-pooled — device reads of their blocks go through the
         counted upload path."""
-        t0 = time.perf_counter()
         t = self.tables
         kx, ky = RELATION_TABLES[relation]
-        segs = np.asarray(batch, dtype=np.intp)
-        if relation == "VV":
-            tabX = tabY = t.T_local[segs]
-            colg = t.LV_global[segs]
-        else:
-            tabX = self._table_host(kx, segs)
-            tabY = self._table_host(ky, segs)
-            colg = getattr(t, _GLOBAL_NAME[ky])[segs]
-        Mh, Lh = ops.relation_block_host(relation, tabX, tabY, colg,
-                                         t.NV, deg=self.deg[relation])
-        dt = time.perf_counter() - t0
+        with self._timed("engine.dispatch", "t_dispatch", relation=relation,
+                         shard=shard):
+            segs = np.asarray(batch, dtype=np.intp)
+            if relation == "VV":
+                tabX = tabY = t.T_local[segs]
+                colg = t.LV_global[segs]
+            else:
+                tabX = self._table_host(kx, segs)
+                tabY = self._table_host(ky, segs)
+                colg = getattr(t, _GLOBAL_NAME[ky])[segs]
+            Mh, Lh = ops.relation_block_host(relation, tabX, tabY, colg,
+                                             t.NV, deg=self.deg[relation])
         n = len(batch)
-        self._bump(t_kernel=dt, kernel_launches=1, segments_produced=n,
+        self._bump(kernel_launches=1, segments_produced=n,
                    degraded_launches=1, degraded_segments=n)
-        self._bump_shard(shard, t_kernel=dt, kernel_launches=1,
-                         segments_produced=n, degraded_launches=1,
-                         degraded_segments=n)
+        self._bump_shard(shard, kernel_launches=1, segments_produced=n,
+                         degraded_launches=1, degraded_segments=n)
         n_int, _ = t.counts(kx if relation != "VV" else "V")
         launch = _Launch(relation, batch, Mh, Lh,
                          [int(n_int[s]) for s in batch], shard=shard,

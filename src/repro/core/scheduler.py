@@ -32,6 +32,10 @@ batch index) propagates to the caller instead of hanging the pool.
 ``workers <= 1`` runs the identical pipeline inline on the calling thread
 (no threads are spawned), so serial callers keep their exact pre-scheduler
 behavior.
+
+Each call of ``prefetch``, ``consume``, ``finalize`` and ``reduce`` runs
+inside a ``consumer.<step>`` span (``core/spans.py``), on the thread that
+makes it.
 """
 
 from __future__ import annotations
@@ -39,6 +43,8 @@ from __future__ import annotations
 import contextlib
 import threading
 from typing import Callable, List, Optional, Sequence
+
+from .spans import spanned
 
 _PENDING = object()   # slot sentinel: batch not finished yet
 
@@ -188,6 +194,12 @@ def run_partitioned(
     if n == 0:
         return
     shares = partition(n, workers, shard_of)
+    consume = spanned("consumer.consume")(consume)
+    reduce = spanned("consumer.reduce")(reduce)
+    if prefetch is not None:
+        prefetch = spanned("consumer.prefetch")(prefetch)
+    if finalize is not None:
+        finalize = spanned("consumer.finalize")(finalize)
 
     if len(shares) == 1 and workers <= 1:
         # inline serial pipeline (no threads): identical order of

@@ -199,7 +199,7 @@ def test_worker_stats_merge_round_trip(setup):
     s = eng.stats
     for f in INT_FIELDS:
         assert getattr(merged, f) == getattr(s, f), f
-    for f in ("t_enqueue", "t_queue", "t_prepare", "t_kernel", "t_sync",
+    for f in ("t_enqueue", "t_queue", "t_prepare", "t_dispatch", "t_sync",
               "t_integrate"):
         assert getattr(merged, f) == pytest.approx(getattr(s, f)), f
     # deterministic merge: same parts, same result
