@@ -84,10 +84,11 @@ def boundary_vertices(ds, pre, batch: int = 4096,
 
     Requires a data structure with engine-native completion (a
     ``RelationEngine`` whose relation set includes TT); TT rows are requested
-    in pipelined batches like every other relation. The device consumer arm
-    (docs/DESIGN.md §6) keeps the completed rows on the accelerator and
-    derives the mask in one fused jit; the host arm is the numpy reference.
-    Both arms are bit-identical."""
+    in pipelined batches like every other relation (``batch`` queries on the
+    host arm; the device completion arm sizes its own chunks). The device
+    consumer arm (docs/DESIGN.md §6) keeps the completed rows on the
+    accelerator and derives the mask in one fused jit; the host arm is the
+    numpy reference. Both arms are bit-identical."""
     sm = pre.smesh
     consume.shard_plan(ds, shards)   # validate; completion follows the plan
     mask = np.zeros(sm.n_vertices, dtype=bool)
@@ -98,8 +99,7 @@ def boundary_vertices(ds, pre, batch: int = 4096,
     if (consume.consumer_mode(ds, consumer) == "device"
             and hasattr(ds, "get_full_dev")):
         M, _ = complete_adjacency(ds, "TT", np.arange(sm.n_tets),
-                                  batch=batch, path="device", out="dev",
-                                  workers=workers)
+                                  path="device", out="dev", workers=workers)
         zeros = jnp.zeros(sm.n_vertices + 1, dtype=bool)
         return np.asarray(_boundary_mask(
             M, jnp.asarray(sm.tets.astype(np.int32)), zeros))

@@ -239,8 +239,9 @@ def _ascending_successors_tt(ds, pre, grad: GradientField,
 
     The device consumer arm (docs/DESIGN.md §6) takes the completed rows as
     device arrays (``complete_adjacency(..., out="dev")`` — no host block
-    round trip) and assembles successors in one fused jit; the host arm is
-    the numpy reference."""
+    round trip, in chunks the completion sizes itself) and assembles
+    successors in one fused jit; the host arm is the numpy reference,
+    completing ``batch`` queries a chunk."""
     nt = pre.smesh.n_tets
     succ = np.arange(nt)
     paired = np.nonzero(grad.pair_t2f >= 0)[0]
@@ -248,9 +249,8 @@ def _ascending_successors_tt(ds, pre, grad: GradientField,
         return succ
     f = grad.pair_t2f[paired]
     if mode == "device" and hasattr(ds, "get_full_dev"):
-        M_dev, _ = complete_adjacency(ds, "TT", paired, batch=batch,
-                                      path="device", out="dev",
-                                      workers=workers)
+        M_dev, _ = complete_adjacency(ds, "TT", paired, path="device",
+                                      out="dev", workers=workers)
         nxt, has = _across_successors(
             M_dev, jnp.asarray(f.astype(np.int32)),
             jnp.asarray(pre.F.astype(np.int32)),

@@ -22,18 +22,28 @@ with a plan/execute split:
     block pool (:meth:`RelationEngine.get_full_dev`), re-resolves every
     (segment, gid) pair to its row by batched binary search over the DEVICE
     inverse maps, and unions/dedups/compacts on device
-    (``kernels/completion_gather.py``) — ONE host round trip per batch.
+    (``kernels/completion_gather.py``) — ONE host round trip per chunk.
   - :func:`execute_completion` is the host reference: one
     :meth:`RelationEngine.get_full` per distinct segment, union as
     vectorized numpy ops. Kept for the A/B benchmark and for data
     structures without a device pool (e.g. the explicit baseline).
 
-:func:`complete_adjacency` drives plan + execute; ``path=`` selects the
+:func:`complete_adjacency` drives plan + execute in pipelined chunks
+(chunk k+1's blocks are prefetched before chunk k executes), which is how
+the algorithm drivers request completed adjacency. ``path=`` selects the
 execute arm ("device" by default on engines exposing ``get_full_dev``,
-"host" otherwise) and ``batch=`` pipelines chunks (plan + prefetch chunk
-k+1 before executing chunk k), which is how the algorithm drivers request
-completed adjacency. Both paths are bit-identical for any chunking.
-Completion work is accounted in ``EngineStats`` (``completion_queries``,
+"host" otherwise). The device arm sizes its chunks from the shape of the
+work (:func:`device_chunk`): the largest power of two of queries whose
+candidate matrix, queries x boundary faces x ``deg[relation]`` i32 entries,
+fits :data:`CHUNK_ENTRIES` — 8,192 TT, 2,048 EE and 1,024 FF queries at
+the default widths. Every chunk pays a fixed set of host round trips
+(pool assembly, pair uploads, the width check's sync), so the chunk is as
+large as the device's transient budget allows. An explicit ``batch=``
+overrides the rule; the host arm chunks only by ``batch=``. The device arm
+releases each block from the engine's device pool after the last chunk
+that consults it (:meth:`RelationEngine.release_dev`). All arms are
+bit-identical for any chunking. Completion work is accounted in
+``EngineStats`` (``completion_chunks``, ``completion_queries``,
 ``completion_fanout_blocks``, ``completion_raw_neighbors`` /
 ``completion_neighbors`` and the derived ``completion_dedup_ratio``), and
 traced as ``completion.*`` spans (``core/spans.py``): the whole call, each
@@ -113,6 +123,7 @@ def plan_completion(eng: RelationEngine, relation: str,
     n = len(ids)
     ns = eng.smesh.n_segments
     if n == 0:
+        eng.stat_bump(completion_chunks=1)
         empty = np.zeros(0, dtype=np.int64)
         return CompletionPlan(relation, ids, empty, empty,
                               empty.astype(np.int32), empty)
@@ -134,12 +145,19 @@ def plan_completion(eng: RelationEngine, relation: str,
             pair_query[ok], pair_seg[ok], pair_row[ok])
     segments = np.unique(pair_seg)
 
-    eng.stat_bump(completion_queries=n,
+    eng.stat_bump(completion_chunks=1, completion_queries=n,
                   completion_fanout_blocks=len(segments))
-    if prefetch:
-        eng.prefetch_many({relation: [int(s) for s in segments]})
-    return CompletionPlan(relation, ids, pair_query, pair_seg,
+    plan = CompletionPlan(relation, ids, pair_query, pair_seg,
                           pair_row.astype(np.int32), segments)
+    if prefetch:
+        _prefetch(eng, plan)
+    return plan
+
+
+def _prefetch(eng, plan: CompletionPlan) -> None:
+    """One non-blocking ``prefetch_many`` for every block ``plan``
+    consults."""
+    eng.prefetch_many({plan.relation: [int(s) for s in plan.segments]})
 
 
 @spanned("completion.execute")
@@ -206,6 +224,23 @@ def execute_completion(eng: RelationEngine, plan: CompletionPlan
 
 # Max (query, segment) pairs per query = number of boundary (k-1)-faces.
 _PAIR_WIDTH = {"E": 2, "F": 3, "T": 4}
+
+# The device arm's per-chunk budget: i32 entries of the candidate matrix
+# (queries x pair width x deg), 1 MiB. Not a parameter: a chunk's HBM
+# temporaries (the gathered candidates, padded to 128 lanes on a TPU, and
+# the union's two sorts) grow with it beside the resident mesh, and
+# peak_hbm_gb may move by 8% at most. At 2 MiB a TT chunk's gather on the
+# 100^3 grid compiles to 35 MB of temporaries, at 1 MiB to 18 MB
+# (tests/test_tpu_compile.py).
+CHUNK_ENTRIES = 1 << 18
+
+
+def device_chunk(relation: str, deg: int) -> int:
+    """Queries in one device-arm completion chunk: the largest power of two
+    whose candidate matrix fits :data:`CHUNK_ENTRIES`, and at least one."""
+    fit = CHUNK_ENTRIES // (_PAIR_WIDTH[relation[0]] * deg)
+    return 1 << max(fit.bit_length() - 1, 0)
+
 
 _pow2 = ops.bucket_rows
 
@@ -442,11 +477,13 @@ def complete_adjacency(
     full preallocated width instead of being trimmed to the realized
     maximum, and no host round trip happens.
 
-    With ``batch=k`` the query list is processed in pipelined chunks: chunk
-    i+1 is planned (and its blocks prefetched) *before* chunk i is executed,
-    so relation production overlaps the gather/union work — the same
-    produce-ahead idiom the algorithm drivers use for every other relation.
-    ``workers=N`` (with ``batch``) partitions the chunk stream across N
+    The query list is processed in pipelined chunks: chunk i+1's blocks are
+    prefetched *before* chunk i is executed, so relation production
+    overlaps the gather/union work — the same produce-ahead idiom the
+    algorithm drivers use for every other relation. The device arm's chunk
+    is :func:`device_chunk` of the relation and its width; ``batch=k`` sets
+    it to k queries instead, and is the host arm's only chunking (one chunk
+    without it). ``workers=N`` partitions the chunk stream across N
     consumer threads through the scheduler (docs/DESIGN.md §8), each
     keeping the plan-ahead pipelining for its own chunks; chunk results
     are assembled in chunk order. The result is bit-identical for any
@@ -483,9 +520,26 @@ def complete_adjacency(
     else:
         execute = execute_completion
     ids = np.asarray(ids, dtype=np.int64).reshape(-1)
+    if batch is None and path == "device":
+        batch = device_chunk(relation, eng.deg[relation])
     if batch is None or batch <= 0 or batch >= len(ids):
-        return execute(eng, plan_completion(eng, relation, ids))
+        rows = execute(eng, plan_completion(eng, relation, ids))
+    else:
+        rows = _run_chunks(eng, relation, ids, batch, execute, out, workers,
+                           release=path == "device")
+    if path == "device":
+        # a block is read in the chunks that consult it; held past them it
+        # keeps device memory from later chunks and the rows' consumers
+        eng.release_dev(relation)
+    return rows
 
+
+def _run_chunks(eng, relation: str, ids: np.ndarray, batch: int, execute,
+                out: str, workers: int, release: bool):
+    """Plan and execute ``ids`` in chunks of ``batch`` queries, producing
+    chunk i+1's blocks before executing chunk i, and assemble the rows in
+    chunk order. With ``release``, a block leaves the device pool once the
+    last chunk that consults it has executed."""
     chunks = [ids[i:i + batch] for i in range(0, len(ids), batch)]
     outs: list = [None] * len(chunks)
     if workers and workers > 1:
@@ -504,11 +558,20 @@ def complete_adjacency(
                         workers=workers, finalize=finalize_chunk,
                         scope=eng, name=f"completion/{relation}")
     else:
-        plans = [plan_completion(eng, relation, chunks[0])]
-        for i in range(len(chunks)):
-            if i + 1 < len(chunks):  # plan + prefetch ahead of the execute
-                plans.append(plan_completion(eng, relation, chunks[i + 1]))
-            outs[i] = execute(eng, plans[i])
+        # plans are host work independent of production: plan every chunk
+        # first, so each block's last consulting chunk is known
+        plans = [plan_completion(eng, relation, c, prefetch=False)
+                 for c in chunks]
+        last = np.full(eng.smesh.n_segments, -1, dtype=np.int64)
+        for i, p in enumerate(plans):
+            last[p.segments] = i
+        _prefetch(eng, plans[0])
+        for i, p in enumerate(plans):
+            if i + 1 < len(plans):   # produce ahead of the execute
+                _prefetch(eng, plans[i + 1])
+            outs[i] = execute(eng, p)
+            if release:
+                eng.release_dev(relation, p.segments[last[p.segments] == i])
     if out == "dev":
         # chunk widths are all deg[relation]: one device concat, no host copy
         return (jnp.concatenate([Mc for Mc, _ in outs]),

@@ -175,6 +175,23 @@ class DevBlockPool:
         self._arrays[aid][2].add(key)
         self._entries[key] = (aid, idx)
 
+    def release(self, relation: str, segments=None) -> int:
+        # contract: holds-lock
+        """Drop the entries of ``relation`` (of ``segments`` only, where
+        given), and each backing array left without an entry. Returns the
+        number of entries dropped."""
+        if segments is None:
+            keys = [k for k in self._entries if k[0] == relation]
+        else:
+            keys = [k for k in ((relation, int(s)) for s in segments)
+                    if k in self._entries]
+        for k in keys:
+            aid, _ = self._entries.pop(k)
+            self._arrays[aid][2].discard(k)
+        for aid in [a for a, (_, _, ks) in self._arrays.items() if not ks]:
+            del self._arrays[aid]
+        return len(keys)
+
     def clear(self) -> int:
         # contract: holds-lock
         """Drop every backing array and entry IN PLACE (the store routes
@@ -276,6 +293,13 @@ class BlockStore:
         for p in self.pools:
             dropped += p.clear()
         return dropped
+
+    def release(self, relation: str, segments=None) -> int:
+        # contract: holds-lock
+        """Drop ``relation``'s blocks (of ``segments`` only, where given)
+        from every shard's device pool, not from the host cache. Returns
+        the number of entries dropped."""
+        return sum(p.release(relation, segments) for p in self.pools)
 
     def cache_nbytes(self) -> int:
         """Bytes retained across the host cache and all device pools."""

@@ -150,6 +150,7 @@ class EngineStats:
     shards_lost: int = 0
     rehomed_segments: int = 0
     # Cross-segment adjacency completion (core/adjacency.py).
+    completion_chunks: int = 0         # plan_completion calls (chunks)
     completion_queries: int = 0        # simplex ids completed
     completion_fanout_blocks: int = 0  # block consultations (see docstring)
     completion_raw_neighbors: int = 0  # gathered entries before dedup/self
@@ -254,16 +255,22 @@ class StatsHost:
         the ``counter`` phase time (and to shard ``shard``'s, where given),
         so with the profiler on a ``t_*`` counter is the sum of its spans.
         ``relation`` is the span's one argument. The caller holds
-        ``self._cond`` when the block starts and when it ends."""
-        t0 = time.perf_counter()
-        with (span(name, relation=relation) if relation else span(name)):
-            try:
-                yield
-            finally:
-                dt = time.perf_counter() - t0
-                self._bump(**{counter: dt})
-                if shard is not None:
-                    self._bump_shard(shard, **{counter: dt})
+        ``self._cond`` when the block starts and when it ends. The clock is
+        read just inside the span's two ends and the counters are bumped
+        after it closes: the bump allocates, and a garbage collection it
+        sets off would lengthen the span and not the counter."""
+        dt = 0.0
+        try:
+            with (span(name, relation=relation) if relation else span(name)):
+                t0 = time.perf_counter()
+                try:
+                    yield
+                finally:
+                    dt = time.perf_counter() - t0
+        finally:
+            self._bump(**{counter: dt})
+            if shard is not None:
+                self._bump_shard(shard, **{counter: dt})
 
     def stat_bump(self, **deltas) -> None:
         """Thread-safe counter update for out-of-engine accounting (the
@@ -688,6 +695,18 @@ class RelationEngine(StatsHost):
             while self._flights:
                 self._sync(self._flights.popleft())
             return self.store.clear_cache()
+
+    def release_dev(self, relation: str,
+                    segments: Optional[Sequence[int]] = None) -> int:
+        """Drop ``relation``'s blocks (those of ``segments`` only, where
+        given) from the device pools under the engine lock, freeing the
+        device memory of every launch array left with no pooled block. The
+        host cache is untouched: a later device read of such a block
+        uploads it from there (``devpool_uploads``), or produces it again
+        if the cache has evicted it. For a consumer done with those blocks.
+        Returns the number of entries dropped."""
+        with self._consumer_entry("release_dev"):
+            return self.store.release(relation, segments)
 
     def cache_nbytes(self) -> int:
         """Bytes retained across the host segment cache and every shard's

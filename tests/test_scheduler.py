@@ -21,6 +21,7 @@ from repro.algorithms import fields
 from repro.algorithms.critical_points import critical_points, total_order
 from repro.algorithms.discrete_gradient import discrete_gradient
 from repro.algorithms.morse_smale import morse_smale
+from repro.core.adjacency import complete_adjacency
 from repro.core.engine import EngineStats, RelationEngine, RelationWidthError
 from repro.core.explicit import ExplicitTriangulation
 from repro.core.mesh import segment_mesh
@@ -32,9 +33,9 @@ from repro.data.meshgen import structured_grid
 RELS = ["VV", "VE", "VF", "VT", "FT", "TT"]
 INT_FIELDS = ("requests", "cache_hits", "inflight_hits", "cache_misses",
               "kernel_launches", "segments_produced", "evictions",
-              "devpool_hits", "devpool_uploads", "completion_queries",
-              "completion_fanout_blocks", "completion_raw_neighbors",
-              "completion_neighbors")
+              "devpool_hits", "devpool_uploads", "completion_chunks",
+              "completion_queries", "completion_fanout_blocks",
+              "completion_raw_neighbors", "completion_neighbors")
 
 
 @pytest.fixture(scope="module")
@@ -194,7 +195,11 @@ def test_worker_stats_merge_round_trip(setup):
     sm, pre, rank = setup
     eng = RelationEngine(pre, RELS, lookahead=4)
     _run_all(eng, pre, rank, 4)
-    assert sorted(eng.worker_stats) >= ["w0", "w1", "w2", "w3"]
+    # the drivers' completions fit one chunk of the device arm's rule on
+    # this mesh; a completion cut in explicit chunks spans all 4 workers
+    complete_adjacency(eng, "TT", np.arange(sm.n_tets), batch=64,
+                       path="device", workers=4)
+    assert set(eng.worker_stats) >= {"w0", "w1", "w2", "w3"}
     merged = eng.merged_worker_stats()
     s = eng.stats
     for f in INT_FIELDS:

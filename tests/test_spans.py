@@ -3,8 +3,10 @@ set, the driver paths record the spans they must, and the engine's phase
 counters are the sums of their spans' host intervals."""
 
 import ast
+import math
 import os
 import pathlib
+import time
 
 import jax
 import numpy as np
@@ -15,6 +17,7 @@ from repro.algorithms.critical_points import critical_points, total_order
 from repro.algorithms.discrete_gradient import discrete_gradient
 from repro.algorithms.morse_smale import morse_smale
 from repro.core import spans
+from repro.core.adjacency import complete_adjacency, device_chunk
 from repro.core.engine import RelationEngine
 from repro.core.mesh import segment_mesh
 from repro.core.segtables import precondition
@@ -142,15 +145,20 @@ def test_critical_points_pass_records_its_spans(mesh, tmp_path,
 
 def test_morse_smale_pass_records_its_spans(mesh, tmp_path):
     pre, rank = mesh
+    nt = pre.smesh.n_tets
 
     def run():
         eng = RelationEngine(pre, MS_RELS, cache_segments=4096)
         grad = discrete_gradient(eng, pre, rank, consumer="device")
         ms = morse_smale(eng, pre, grad, consumer="device", adjacency="tt",
                          batch_segments=2)
-        return eng, ms
+        ms_chunks = eng.stats.completion_chunks
+        # and a completion cut into several chunks by an explicit batch
+        complete_adjacency(eng, "TT", np.arange(nt), batch=256,
+                           path="device", out="dev")
+        return eng, grad, ms, ms_chunks
 
-    (eng, ms), got = _traced(tmp_path, run)
+    (eng, grad, ms, ms_chunks), got = _traced(tmp_path, run)
     assert len(ms.dest_min) == pre.smesh.n_vertices
     must = {"driver.discrete_gradient", "driver.morse_smale",
             "driver.ms.descending", "driver.ms.successors",
@@ -159,10 +167,59 @@ def test_morse_smale_pass_records_its_spans(mesh, tmp_path):
             "completion.plan", "completion.execute",
             "completion.width_check", "consumer.read_dev"}
     assert must <= set(got), must - set(got)
+    # the pass's successors complete the paired tets in the rule's chunks
+    paired = int((grad.pair_t2f >= 0).sum())
+    assert ms_chunks == math.ceil(paired / device_chunk("TT", eng.deg["TT"]))
+    assert ms_chunks == 1
     # one plan and one width check per completed chunk
-    assert len(got["completion.plan"]) == len(got["completion.width_check"])
-    assert len(got["completion.plan"]) > 1
+    chunks = ms_chunks + math.ceil(nt / 256)
+    assert len(got["completion.plan"]) == eng.stats.completion_chunks
+    assert eng.stats.completion_chunks == chunks
+    assert len(got["completion.width_check"]) == chunks
+    assert len(got["completion.complete"]) == 2
     _assert_counters_are_span_sums(eng.stats, got)
+
+
+def test_counter_bookkeeping_stays_outside_its_span(mesh, tmp_path):
+    """A phase counter's bookkeeping runs after its span closes: a pause
+    there (a garbage collection set off by the bump's allocations) must
+    lengthen neither the span nor the counter."""
+    pre, rank = mesh
+
+    class PausingEngine(RelationEngine):
+        def _bump(self, **deltas):
+            if any(k.startswith("t_") for k in deltas):
+                time.sleep(0.002)
+            super()._bump(**deltas)
+
+    def run():
+        eng = PausingEngine(pre, ["VV", "VT"], cache_segments=4096,
+                            async_dispatch=False)
+        critical_points(eng, pre, rank, consumer="device")
+        return eng
+
+    eng, got = _traced(tmp_path, run)
+    assert len(got["engine.sync"]) > 0
+    _assert_counters_are_span_sums(eng.stats, got)
+
+
+@pytest.mark.parametrize("batch", [None, 300])
+def test_completion_chunks_are_plan_spans(mesh, tmp_path, batch):
+    """``completion_chunks`` counts the plans: ceil(n / chunk) for the
+    rule's chunk or an explicit batch, one ``completion.plan`` span each."""
+    pre, _ = mesh
+    nt = pre.smesh.n_tets
+
+    def run():
+        eng = RelationEngine(pre, ["TT"], cache_segments=4096)
+        complete_adjacency(eng, "TT", np.arange(nt), batch=batch,
+                           path="device")
+        return eng
+
+    eng, got = _traced(tmp_path, run)
+    chunk = batch or device_chunk("TT", eng.deg["TT"])
+    assert eng.stats.completion_chunks == math.ceil(nt / chunk)
+    assert len(got["completion.plan"]) == eng.stats.completion_chunks
 
 
 def test_spanned_keeps_the_function():

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.adjacency import device_chunk
 from repro.core.engine import RelationEngine
 from repro.core.mesh import segment_mesh
 from repro.core.segtables import OFFLOADED_RELATIONS, RELATION_TABLES
@@ -88,12 +89,17 @@ def test_xla_relation_block_compiles(relation, one_chip):
     assert compiled.memory_analysis() is not None
 
 
-def test_xla_completion_gather_compiles(one_chip):
+@pytest.mark.parametrize("n,P,S", [(1024, 4096, 64), (8192, 32768, 128)],
+                         ids=["batch_1024", "tt_chunk"])
+def test_xla_completion_gather_compiles(one_chip, n, P, S):
     """TT completion on the 100^3 grid: 11,482,816 (segment, tet)
     appearances, too many for the combined i32 key, so the lexicographic
-    resolve (``inv_key=None``) is the one compiled."""
-    S, R, deg = 64, WIDTH["T"][0], ops.DEFAULT_DEG["TT"]
-    K, P, n = 11_482_816, 4096, 1024
+    resolve (``inv_key=None``) is the one compiled. Shapes: a chunk of
+    1,024 queries, and the device arm's TT chunk (``adjacency.
+    device_chunk``: 8,192 queries, four pairs each, a 128-slot pool),
+    whose temporaries guard ``peak_hbm_gb``: under 32 MB."""
+    R, deg = WIDTH["T"][0], ops.DEFAULT_DEG["TT"]
+    K = 11_482_816
     args = (_sds((S, R, deg), one_chip), _sds((S, R), one_chip),
             _sds((K,), one_chip), _sds((K,), one_chip),
             _sds((K,), one_chip), None,
@@ -101,7 +107,10 @@ def test_xla_completion_gather_compiles(one_chip):
             _sds((P,), one_chip), _sds((n, 4), one_chip))
     compiled = cg._gather_union_xla.lower(
         *args, deg_out=deg, n_global=5_821_794).compile()
-    assert compiled.memory_analysis() is not None
+    mem = compiled.memory_analysis()
+    assert mem is not None
+    if n == device_chunk("TT", deg):
+        assert mem.temp_size_in_bytes < 32e6
 
 
 @pytest.mark.parametrize("jit_name", ["across_successors", "boundary_mask"])
