@@ -52,7 +52,7 @@ class Ids:
     def __init__(self, raw, cx: ref.Complex, sm, pre):
         self.cx = cx
         self.E_prog = np.asarray(pre.E)
-        v = raw.grid_ids(sm.points)
+        v = raw.raw_ids(sm.points)
         bad = int((v < 0).sum())
         bad += int((np.asarray(sm.scalars)[v >= 0]
                     != raw.scalars[v[v >= 0]]).sum())

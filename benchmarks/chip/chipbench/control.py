@@ -23,7 +23,7 @@ def readings(root: str, workload: str, seed: int) -> dict:
     """The numbers compared for one seed, with the pass run on the
     bfloat16 order and the reference on the float32 order."""
     cell = harness.load_cell(root, workload)
-    raw = meshgen.generate(cell.config, seed)
+    raw = meshgen.generate(cell.config, seed, cell.bench_dir)
     rank = meshgen.injective_rank(raw.scalars)
     low = meshgen.injective_rank(
         raw.scalars.astype(ml_dtypes.bfloat16).astype(np.float32))

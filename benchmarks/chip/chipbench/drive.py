@@ -113,7 +113,7 @@ class Program:
                                capacity=int(config["capacity"]))
         self.pre = precondition(self.sm, relations=self.relations)
         log(f"segment_mesh + precondition {time.perf_counter() - t0:.6f}s")
-        self.raw_of_prog_v = raw.grid_ids(self.sm.points)
+        self.raw_of_prog_v = raw.raw_ids(self.sm.points)
         self.state: Dict[str, object] = {
             "rank": np.asarray(rank_raw)[self.raw_of_prog_v]}
         for name, spec in traffic.get("inputs", {}).items():

@@ -1,10 +1,12 @@
 """The harness: one cell of ``BENCHMARK.json``, run once.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric is found by name: ``BENCHMARK.json``'s ``configs[].file``,
-``traffic/<traffic>.json`` and ``metrics/<metric>.py`` (a module with
-``read(run)`` that returns the metric's value, or None where the run holds
-nothing to read). A new cell is files plus one ``workloads`` entry.
+Everything that belongs to one configuration, mesh kind, traffic mix or
+per-layer metric is found by name: ``BENCHMARK.json``'s ``configs[].file``,
+the generator ``meshes/<mesh>.py`` that the configuration file names (see
+:mod:`.meshgen`), ``traffic/<traffic>.json`` and ``metrics/<metric>.py`` (a
+module with ``read(run)`` that returns the metric's value, or None where
+the run holds nothing to read). A new cell is files plus one ``workloads``
+entry; a new mesh kind is one more file.
 
 Order of a run: check the device, generate the mesh from the seed, set the
 program up (segment, precondition, the traffic's inputs), one whole warm-up
@@ -18,7 +20,6 @@ metrics are read from it and from the window's counters.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 import os
 import shutil
@@ -28,7 +29,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import check, drive, meshgen, roofline, xplane
+from . import check, drive, load_file, meshgen, roofline, xplane
 from . import reference as ref
 from .meter import CompileMeter
 
@@ -87,12 +88,8 @@ def load_cell(root: str, workload: str) -> Cell:
 
 def load_reader(bench_dir: str, name: str) -> Callable:
     """``read`` of ``metrics/<name>.py``."""
-    path = os.path.join(bench_dir, "metrics", name + ".py")
-    mod_name = "chipbench_metric_" + name.replace(".", "_").replace("-", "_")
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(os.path.join(bench_dir, "metrics", name + ".py"),
+                     "chipbench_metric_" + name).read
 
 
 @dataclasses.dataclass
@@ -244,13 +241,13 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     peaks = roofline.peaks(dev.device_kind) if require_tpu else {}
 
     t0 = time.perf_counter()
-    raw = meshgen.generate(cell.config, seed)
+    raw = meshgen.generate(cell.config, seed, cell.bench_dir)
     rank_raw = meshgen.injective_rank(raw.scalars)
     log(f"mesh generated {time.perf_counter() - t0:.6f}s")
     program = drive.Program(raw, rank_raw, cell.config, cell.traffic,
                             log=log)
     log(f"mesh vertices={raw.n_vertices} tets={raw.n_tets} "
-        f"segments={program.sm.n_segments} kept_share={raw.kept_share:.6f} "
+        f"segments={program.sm.n_segments} facts={json.dumps(raw.facts)} "
         f"setup_inputs={sorted(cell.traffic.get('inputs', {}))}")
     warm = program.run_pass()
     log(f"warm-up pass {warm.seconds:.6f}s")

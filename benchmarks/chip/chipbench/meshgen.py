@@ -1,39 +1,58 @@
 """Deployment meshes generated from a configuration file and a seed.
 
-A configuration names a regular scalar volume, tetrahedralized by the Kuhn
-(Freudenthal) split of each voxel into six tets around its main diagonal,
-with an optional mask that removes null-valued voxels first (GALE,
-arXiv:2507.15230 §5). The field is a sum of seeded Gaussian bumps. The same
-configuration and seed give the same points, tets and scalars, bit for bit.
-Nothing here imports the program under test.
+A configuration file names its mesh's generator under ``"mesh"``: the
+module ``meshes/<mesh>.py`` of the benchmark's directory, found by name as
+traffic mixes and per-layer metrics are. Its ``generate(config, seed)``
+returns a :class:`RawMesh`, which :func:`generate` checks before anyone
+reads it. The same configuration and seed give the same points, tets and
+scalars, bit for bit. What holds for every mesh lives here: the checks, the
+map of program vertices onto raw ones by coordinates, the seeded Gaussian
+field and the injective vertex order. Nothing here imports the program
+under test.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
-
+import os
 import numpy as np
 
-# Kuhn split: six tets per voxel sharing the diagonal from corner 0 to
-# corner 7; corners are bit-coded x + 2y + 4z.
-KUHN_TETS = ((0, 1, 3, 7), (0, 1, 5, 7), (0, 2, 3, 7),
-             (0, 2, 6, 7), (0, 4, 5, 7), (0, 4, 6, 7))
-CORNERS = np.array([[b & 1, (b >> 1) & 1, (b >> 2) & 1] for b in range(8)])
+from . import load_file
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class MeshError(ValueError):
+    """A generator returned a mesh that breaks what every mesh must be."""
+
+
+def _coordinate_runs(rows: np.ndarray):
+    """The order of a stable sort of float32 ``rows`` by their exact
+    coordinates (-0.0 read as 0.0), and for each position of it the
+    position where its run of equal rows starts."""
+    bits = (np.asarray(rows, np.float32) + np.float32(0)).view(np.uint32)
+    bits = bits.astype(np.uint64)
+    hi, lo = (bits[:, 0] << np.uint64(32)) | bits[:, 1], bits[:, 2]
+    order = np.lexsort((lo, hi))
+    hi, lo = hi[order], lo[order]
+    new = np.ones(len(order), bool)
+    new[1:] = (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1])
+    return order, np.maximum.accumulate(
+        np.where(new, np.arange(len(order)), 0))
 
 
 @dataclasses.dataclass
 class RawMesh:
     """The benchmark's own copy of a generated mesh: ``points`` (nv, 3)
-    f32 integer grid coordinates, ``tets`` (nt, 4) i64 rows sorted
-    ascending, ``scalars`` (nv,) f32, ``grid`` the vertex counts per axis
-    and ``kept_share`` the share of voxels the mask kept."""
+    float32 rows, all different, ``tets`` (nt, 4) int64 rows of four
+    vertex ids in ascending order, ``scalars`` (nv,) float32, and
+    ``facts``, what the generator reports of the mesh (logged at set-up,
+    read by nothing)."""
 
     points: np.ndarray
     tets: np.ndarray
     scalars: np.ndarray
-    grid: tuple
-    kept_share: float
+    facts: dict = dataclasses.field(default_factory=dict)
 
     @property
     def n_vertices(self) -> int:
@@ -43,19 +62,58 @@ class RawMesh:
     def n_tets(self) -> int:
         return len(self.tets)
 
-    def grid_ids(self, points: np.ndarray) -> np.ndarray:
-        """Raw vertex id of each row of ``points`` (grid coordinates), or
-        -1 where no vertex of this mesh sits there."""
-        nx, ny, nz = self.grid
-        p = np.rint(np.asarray(points, np.float64)).astype(np.int64)
-        full = (p[:, 0] * ny + p[:, 1]) * nz + p[:, 2]
-        lut = np.full(nx * ny * nz, -1, np.int64)
-        mine = np.rint(self.points.astype(np.float64)).astype(np.int64)
-        lut[(mine[:, 0] * ny + mine[:, 1]) * nz + mine[:, 2]] = np.arange(
-            len(mine))
-        ok = ((p >= 0).all(1) & (p[:, 0] < nx) & (p[:, 1] < ny)
-              & (p[:, 2] < nz))
-        return np.where(ok, lut[np.where(ok, full, 0)], -1)
+    def raw_ids(self, points: np.ndarray) -> np.ndarray:
+        """Raw vertex id of each float32 row of ``points``: the vertex of
+        this mesh at exactly those coordinates, or -1 where none is. One
+        stable sort of this mesh's rows followed by ``points``'s: a row of
+        ``points`` finds its vertex at the start of its run."""
+        n = self.n_vertices
+        order, start = _coordinate_runs(np.concatenate(
+            [self.points, np.asarray(points, np.float32)]))
+        first = order[start]
+        query = order >= n
+        out = np.full(len(order) - n, -1, np.int64)
+        out[order[query] - n] = np.where(first[query] < n, first[query], -1)
+        return out
+
+
+def check_mesh(raw: RawMesh, generator: str) -> RawMesh:
+    """``raw`` if it is what every generator must return; raises
+    :class:`MeshError` naming ``generator`` otherwise."""
+    def refuse(what):
+        raise MeshError(f"mesh generator {generator!r}: {what}")
+    p, t, s = raw.points, raw.tets, raw.scalars
+    if p.dtype != np.float32 or p.ndim != 2 or p.shape[1] != 3:
+        refuse(f"points must be (nv, 3) float32, got {p.shape} {p.dtype}")
+    if not np.isfinite(p).all():
+        refuse("points must be finite")
+    _, start = _coordinate_runs(p)
+    repeated = int((start != np.arange(len(p))).sum())
+    if repeated:
+        refuse(f"{repeated} points repeat another point's coordinates")
+    if t.dtype != np.int64 or t.ndim != 2 or t.shape[1] != 4:
+        refuse(f"tets must be (nt, 4) int64, got {t.shape} {t.dtype}")
+    if len(t) and (t.min() < 0 or t.max() >= len(p)):
+        refuse(f"tet vertex ids must lie in [0, {len(p)}), got "
+               f"[{t.min()}, {t.max()}]")
+    unsorted = int((t[:, 1:] <= t[:, :-1]).any(1).sum())
+    if unsorted:
+        refuse(f"{unsorted} tet rows are not four ids in ascending order")
+    if s.dtype != np.float32 or s.shape != (len(p),):
+        refuse(f"scalars must be ({len(p)},) float32, got {s.shape} "
+               f"{s.dtype}")
+    return raw
+
+
+def generate(config: dict, seed: int,
+             bench_dir: str = BENCH_DIR) -> RawMesh:
+    """The mesh of ``config`` (a configuration file's JSON object) with the
+    field of ``seed``, made by ``<bench_dir>/meshes/<config["mesh"]>.py``
+    and checked by :func:`check_mesh`."""
+    name = config["mesh"]
+    mod = load_file(os.path.join(bench_dir, "meshes", name + ".py"),
+                    "chipbench_mesh_" + name)
+    return check_mesh(mod.generate(config, seed), name)
 
 
 def gaussian_field(seed: int, k: int, sigma: float, scale: float):
@@ -74,43 +132,6 @@ def gaussian_field(seed: int, k: int, sigma: float, scale: float):
             acc += s * np.exp(-d2 / (2 * sigma * sigma))
         return acc.astype(np.float32)
     return fn
-
-
-def generate(config: dict, seed: int) -> RawMesh:
-    """The mesh of ``config`` (a configuration file's JSON object) with
-    the field of ``seed``. Keys read: ``grid`` [nx, ny, nz] vertices per
-    axis, ``field`` {``k``, ``sigma_per_span``, ``scale_per_span``} with
-    span the largest grid extent, and ``mask`` (null, or
-    {``drop_below_quantile`` q, ``field_seed`` s}: the voxels where the
-    field of seed s, at the voxel centre, lies below the q-quantile of
-    those values are removed). The mask's field has a seed of its own, so
-    the mesh is the same for every seed and only the analysed field
-    changes with ``seed``."""
-    nx, ny, nz = (int(v) for v in config["grid"])
-    span = float(max(nx, ny, nz))
-    f = config["field"]
-
-    def field_of(s):
-        return gaussian_field(s, int(f["k"]), f["sigma_per_span"] * span,
-                              f["scale_per_span"] * span)
-    cx, cy, cz = np.meshgrid(np.arange(nx - 1), np.arange(ny - 1),
-                             np.arange(nz - 1), indexing="ij")
-    cells = np.stack([cx, cy, cz], axis=-1).reshape(-1, 3)
-    n_cells = len(cells)
-    mask: Optional[dict] = config.get("mask")
-    if mask:
-        centre = field_of(int(mask["field_seed"]))(cells + 0.5)
-        cut = np.quantile(centre, float(mask["drop_below_quantile"]))
-        cells = cells[centre >= cut]
-    corners = cells[:, None, :] + CORNERS[None, :, :]          # (c, 8, 3)
-    cid = (corners[..., 0] * ny + corners[..., 1]) * nz + corners[..., 2]
-    tets = np.concatenate([cid[:, list(t)] for t in KUHN_TETS], axis=0)
-    used, tets = np.unique(tets, return_inverse=True)
-    tets = np.sort(tets.reshape(-1, 4).astype(np.int64), axis=1)
-    points = np.stack([used // (ny * nz), (used // nz) % ny, used % nz],
-                      axis=1).astype(np.float32)
-    return RawMesh(points=points, tets=tets, scalars=field_of(seed)(points),
-                   grid=(nx, ny, nz), kept_share=len(cells) / n_cells)
 
 
 def injective_rank(scalars: np.ndarray) -> np.ndarray:

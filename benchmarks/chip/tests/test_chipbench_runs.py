@@ -1,9 +1,10 @@
-"""Whole benchmark runs on the CPU at a tiny grid: the look for a chip is
-skipped, everything else runs as on the chip. A sound run is correct; the
-control (the bfloat16 vertex order) and each fault planted in the timed
-path come out not correct; and a new cell, configuration, traffic mix and
-per-layer metric are added as files plus one ``BENCHMARK.json`` entry each,
-with no edit to the harness."""
+"""Whole benchmark runs on the CPU, each configuration cut to its own
+``tiny`` sizes: the look for a chip is skipped, everything else runs as on
+the chip. A sound run is correct; the control (the bfloat16 vertex order)
+and each fault planted in the timed path come out not correct; and a new
+cell, configuration, mesh kind, traffic mix and per-layer metric are added
+as files plus one ``BENCHMARK.json`` entry each, with no edit to the
+harness."""
 
 import json
 import os
@@ -17,20 +18,22 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(BENCH))
 sys.path.insert(0, BENCH)
 
-from chipbench import control, harness  # noqa: E402
+from chipbench import control, harness, meshgen  # noqa: E402
 
-TINY = {"box": [9, 8, 7], "masked": [11, 10, 9]}
 SEED = 2 ** 31 + 77
 # cells of traffic mixes the benchmark keeps for a later PR (PERF.md, Open
 # questions): each runs here as a cell of the tiny checkout
 with open(os.path.join(BENCH, "tests", "data", "later_cells.json"),
           encoding="utf-8") as _f:
     LATER = json.load(_f)
+with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"] + LATER]
 
 
 def _tiny_root(path):
-    """A checkout holding the benchmark with each configuration cut to a
-    tiny grid (same files otherwise), and the cells kept for later."""
+    """A checkout holding the benchmark with each configuration updated by
+    its own ``tiny`` object (same files otherwise), and the cells kept for
+    later."""
     shutil.copytree(BENCH, os.path.join(path, "benchmarks", "chip"),
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
@@ -40,7 +43,7 @@ def _tiny_root(path):
         p = os.path.join(path, c["file"])
         with open(p, encoding="utf-8") as f:
             cfg = json.load(f)
-        cfg["grid"] = TINY[c["name"]]
+        cfg.update(cfg["tiny"])
         with open(p, "w", encoding="utf-8") as f:
             json.dump(cfg, f)
     with open(os.path.join(path, "BENCHMARK.json"), "w",
@@ -62,8 +65,7 @@ def _run(root, workload, trace=False, seed=SEED):
                             log=lambda m: None)
 
 
-@pytest.mark.parametrize("workload", ["box.cp", "masked.cp", "box.dg",
-                                      "masked.ms"])
+@pytest.mark.parametrize("workload", CELLS)
 def test_sound_run_is_correct(root, workload):
     r = _run(root, workload)
     assert r["correct"], r["checks"]
@@ -96,6 +98,15 @@ def _alter_first_answer(fn):
     def altered(*a, **k):
         t = fn(*a, **k)
         return t.at[0].set(t[0] + 1)
+    return altered
+
+
+def _flip_first_critical_vertex(fn):
+    def altered(*a, **k):
+        crit_vertex, *rest = fn(*a, **k)
+        flipped = crit_vertex.at[0].set(
+            (~crit_vertex[0].astype(bool)).astype(crit_vertex.dtype))
+        return (flipped, *rest)
     return altered
 
 
@@ -155,6 +166,9 @@ def _no_op(*a, **k):
 FAULTS = {
     "answer_altered": ("box.cp", "repro.algorithms.critical_points",
                        "_classify_batch", _alter_first_answer, ("cp_types",)),
+    "answer_altered_dg": ("box.dg", "repro.algorithms.discrete_gradient",
+                          "_lower_star_batch", _flip_first_critical_vertex,
+                          ("dg_stars",)),
     "block_altered": ("masked.cp", "repro.kernels.ops", "relation_block",
                       _drop_first_neighbour, ("blocks_VV", "blocks_VT")),
     "half_the_batch": ("box.cp", "repro.algorithms.critical_points",
@@ -240,3 +254,88 @@ def test_new_cell_is_files_plus_one_entry(tmp_path):
     # the cells already there do not list the new metric
     assert "engine.launches" not in _run(path, "box.cp", trace=True)[
         "metrics"]
+
+
+# A mesh kind with non-integer coordinates: the Kuhn mesh of the grid with
+# every vertex moved by a seeded offset in (-a, a)^3, same topology.
+JITTER = '''import os
+
+import numpy as np
+
+from chipbench import meshgen
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def generate(config, seed):
+    raw = meshgen.generate(dict(config, mesh="kuhn"), seed, BENCH)
+    a = float(config["jitter"]["amplitude"])
+    rng = np.random.default_rng(int(config["jitter"]["seed"]))
+    raw.points = (raw.points + rng.uniform(-a, a, raw.points.shape)
+                  ).astype(np.float32)
+    raw.facts["jitter"] = a
+    return raw
+'''
+
+
+@pytest.fixture(scope="module")
+def jitter_root(tmp_path_factory):
+    """The tiny checkout with a new mesh kind added as files: the generator
+    ``meshes/jitter.py``, its configuration file with ``mesh`` and
+    ``tiny``, and one ``configs`` entry and one ``workloads`` entry for each
+    of two traffic mixes."""
+    path = str(tmp_path_factory.mktemp("jitter"))
+    spec = _tiny_root(path)
+    bench = os.path.join(path, "benchmarks", "chip")
+    with open(os.path.join(bench, "meshes", "jitter.py"), "w",
+              encoding="utf-8") as f:
+        f.write(JITTER)
+    with open(os.path.join(bench, "configs", "box.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    cfg.update(name="jitter", mesh="jitter", grid=[30, 30, 30],
+               tiny={"grid": [9, 7, 8]},
+               jitter={"amplitude": 0.3, "seed": 12})
+    cfg.update(cfg["tiny"])
+    with open(os.path.join(bench, "configs", "jitter.json"), "w",
+              encoding="utf-8") as f:
+        json.dump(cfg, f)
+    spec["configs"].append({"name": "jitter", "source": "test",
+                            "file": "benchmarks/chip/configs/jitter.json",
+                            "reduced": [], "why": "test"})
+    for traffic in ("cp", "dg"):
+        spec["workloads"].append({"name": "jitter." + traffic,
+                                  "config": "jitter", "traffic": traffic,
+                                  "chips": 1, "why": "test"})
+    with open(os.path.join(path, "BENCHMARK.json"), "w",
+              encoding="utf-8") as f:
+        json.dump(spec, f)
+    return path
+
+
+@pytest.mark.parametrize("traffic", ["cp", "dg"])
+def test_new_mesh_kind_is_files_plus_one_entry(jitter_root, traffic):
+    logged = []
+    r = harness.run_cell(jitter_root, "jitter." + traffic, SEED, 0.05,
+                         False, time.perf_counter(), require_tpu=False,
+                         log=logged.append)
+    assert r["correct"], r["checks"]
+    assert r["checks"]["mesh_tables"]["value"] == 0
+    assert any('"jitter": 0.3' in m for m in logged)
+
+
+def _two_vertices_swapped(fn):
+    def swapped(self, points):
+        out = fn(self, points)
+        out[[0, 1]] = out[[1, 0]]
+        return out
+    return swapped
+
+
+def test_new_mesh_kind_with_a_wrong_map_is_not_correct(jitter_root,
+                                                        monkeypatch):
+    monkeypatch.setattr(meshgen.RawMesh, "raw_ids",
+                        _two_vertices_swapped(meshgen.RawMesh.raw_ids))
+    r = _run(jitter_root, "jitter.cp")
+    assert not r["correct"], r["checks"]
+    assert r["checks"]["mesh_tables"]["value"] > 0
